@@ -44,19 +44,27 @@ from repro.core import imbue
 from repro.core.tm import TMConfig, include_mask, init_ta_state, literals
 from repro.core.variations import VariationConfig
 from repro.kernels import autotune, ops
-from repro.roofline.analysis import HBM_BW, PEAK_FLOPS
+from repro.roofline.analysis import TARGET_KIND, peaks
+
+
+def roofline_kind() -> str:
+    """The chip whose peaks the analytic roofline uses: the attached TPU
+    (an unlisted one raises in ``peaks``), else the dry-run's target."""
+    dev = jax.devices()[0]
+    return dev.device_kind if dev.platform == "tpu" else TARGET_KIND
 
 
 def kernel_roofline(b, c, l, *, bt, ct, kt, dtype_bytes=4,
                     analog=False, width=32):
-    """Analytic per-kernel roofline on TPU v5e constants."""
+    """Analytic per-kernel roofline on :func:`roofline_kind`'s peaks."""
     flops = 2.0 * b * c * l * (2 if analog else 1)   # on+leak paths
     hbm = dtype_bytes * (b * l + c * l * (2 if analog else 1) + b * c / 8)
     vmem = dtype_bytes * (bt * kt * (2 if analog else 1)
                           + kt * ct * (2 if analog else 1) + bt * ct)
     intensity = flops / hbm
-    t_comp = flops / PEAK_FLOPS
-    t_mem = hbm / HBM_BW
+    pk = peaks(roofline_kind())
+    t_comp = flops / pk.bf16_flops
+    t_mem = hbm / pk.hbm_bw
     # MXU efficiency: contraction dim per pass (128 ideal)
     contract = width if analog else min(kt, 512)
     mxu_eff = min(contract, 128) / 128.0
@@ -250,4 +258,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

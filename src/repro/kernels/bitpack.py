@@ -22,6 +22,8 @@ for the packed representation used end-to-end:
   plane-packed analog kernels: one ``[kw, ct]`` include-index word
   block -> ``[32*kw, ct]`` f32 bits, i.e. the transposed-plane layout
   the conductance reconstruction consumes.
+* :func:`word_chunks` — the ``[K chunks, B, kw]`` literal-word layout
+  the packed kernels block over, so that each block's lane dim is full.
 
 The layouts of the np and jnp packers are asserted identical by the
 round-trip tests (``tests/test_packed*.py``).
@@ -100,7 +102,7 @@ def unpack_words_f32(words: jax.Array, *, n_bits: int) -> jax.Array:
         raise ValueError(f"n_bits={n_bits} != {kw}*{WORD}")
     expanded = jnp.repeat(words, WORD, axis=1)                 # [bt, n_bits]
     shift = jax.lax.broadcasted_iota(jnp.uint32, (bt, n_bits), 1) % WORD
-    return ((expanded >> shift) & jnp.uint32(1)).astype(jnp.float32)
+    return _bits_f32((expanded >> shift) & jnp.uint32(1))
 
 
 def unpack_words_f32_cols(words: jax.Array, *, n_bits: int) -> jax.Array:
@@ -118,4 +120,28 @@ def unpack_words_f32_cols(words: jax.Array, *, n_bits: int) -> jax.Array:
         raise ValueError(f"n_bits={n_bits} != {kw}*{WORD}")
     expanded = jnp.repeat(words, WORD, axis=0)                 # [n_bits, ct]
     shift = jax.lax.broadcasted_iota(jnp.uint32, (n_bits, ct), 0) % WORD
-    return ((expanded >> shift) & jnp.uint32(1)).astype(jnp.float32)
+    return _bits_f32((expanded >> shift) & jnp.uint32(1))
+
+
+def _bits_f32(bits: jax.Array) -> jax.Array:
+    """0/1 ``uint32`` -> f32 through ``int32``: Mosaic has no
+    ``uint32 -> f32`` cast, and for 0/1 values the detour is exact."""
+    return bits.astype(jnp.int32).astype(jnp.float32)
+
+
+def word_chunks(words: jax.Array, kw: int) -> jax.Array:
+    """``[B, Lw] uint32`` -> ``[Lw/kw, B, kw]``: the K chunks of a word
+    matrix moved onto a leading axis.
+
+    A Pallas TPU block's last dim must be a multiple of 128 lanes or the
+    array's full dim, and a dynamic lane offset must be provably
+    128-aligned.  A ``kw``-word chunk (8 or 16 words at the default
+    tiles) is neither on a ``[B, Lw]`` array; as the FULL last dim of
+    this layout it is legal, and a chunk is picked by a block index or a
+    dynamic index on the untiled leading axis.  ``Lw`` must already be a
+    multiple of ``kw``.
+    """
+    b, lw = words.shape
+    if lw % kw:
+        raise ValueError(f"word count {lw} not divisible by chunk {kw}")
+    return words.reshape(b, lw // kw, kw).transpose(1, 0, 2)

@@ -1,6 +1,6 @@
 """Pallas TPU kernels for digital TM clause evaluation + fused inference.
 
-The crossbar insight, MXU-shaped (DESIGN.md §2): clause evaluation is a
+The crossbar insight, MXU-shaped (paper §II): clause evaluation is a
 binary matmul ``viol[b, c] = sum_i lit0[b, i] * include[c, i]`` followed by
 a threshold (``viol == 0``), and class sums are a second (tiny) matmul
 against a signed polarity one-hot.  Fusing threshold + polarity matmul into
@@ -29,7 +29,10 @@ bits invert to 1 but include pad bits are 0, so ``AND`` kills them.
 Blocks are MXU-aligned (128 multiples) in the unpacked path; packed K
 tiles are multiples of 32 bits.  All accumulation stays in VMEM scratch.
 Inputs arrive pre-transposed (``include_t [L, C]`` / ``[L/32, C]``) so
-the contraction is a plain row-major sweep.
+the contraction is a plain row-major sweep.  Packed literal words reach
+the kernels as ``[K chunks, B, kw]`` (``bitpack.word_chunks``): a K tile
+of ``kw`` words is then a full lane dim, which Mosaic accepts, where a
+``kw``-lane block of ``[B, L/32]`` is neither full nor 128-aligned.
 """
 
 from __future__ import annotations
@@ -41,8 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-from repro.kernels.bitpack import WORD
+from repro.kernels.bitpack import WORD, word_chunks
 
 
 def clause_eval_kernel(lit0_ref, inc_t_ref, out_ref, acc_ref):
@@ -86,18 +88,18 @@ def tm_infer_kernel(lit0_ref, inc_t_ref, pol_ref, out_ref, acc_ref):
                                 preferred_element_type=jnp.float32)
 
 
-def _packed_viol_block(litw_ref, incw_t_ref, acc, kw):
+def _packed_viol_block(lit_words, inc_words, acc, kw):
     """Violation counts for one packed K tile: AND + popcount per word.
 
-    ``litw_ref`` holds raw literal words (NOT pre-inverted — the packed
-    wire format is the literals themselves); the kernel inverts in
-    registers.  Each word contributes
+    ``lit_words`` ``[bt, kw]`` holds raw literal words (NOT pre-inverted
+    — the packed wire format is the literals themselves); the kernel
+    inverts in registers.  Each word contributes
     ``popcount((~lit_w)[bt, 1] & inc_w[1, ct])`` — an outer bitwise AND
     broadcast on the VPU, no MXU pass.
     """
     for w in range(kw):
-        l0 = (~litw_ref[:, w])[:, None]                  # [bt, 1] uint32
-        iw = incw_t_ref[w, :][None, :]                   # [1, ct] uint32
+        l0 = ~lit_words[:, w:w + 1]                      # [bt, 1] uint32
+        iw = inc_words[w:w + 1, :]                       # [1, ct] uint32
         acc = acc + jax.lax.population_count(l0 & iw).astype(jnp.int32)
     return acc
 
@@ -111,7 +113,8 @@ def clause_eval_packed_kernel(litw_ref, incw_t_ref, out_ref, acc_ref, *, kw):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] = _packed_viol_block(litw_ref, incw_t_ref, acc_ref[...], kw)
+    acc_ref[...] = _packed_viol_block(litw_ref[...], incw_t_ref[...],
+                                      acc_ref[...], kw)
 
     @pl.when(k == nk - 1)
     def _emit():
@@ -130,7 +133,8 @@ def tm_infer_packed_kernel(litw_ref, incw_t_ref, pol_ref, out_ref, acc_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] = _packed_viol_block(litw_ref, incw_t_ref, acc_ref[...], kw)
+    acc_ref[...] = _packed_viol_block(litw_ref[...], incw_t_ref[...],
+                                      acc_ref[...], kw)
 
     @pl.when(jnp.logical_and(k == nk - 1, c == 0))
     def _init_out():
@@ -175,8 +179,7 @@ def tm_infer_planes_kernel(litw_ref, incw_hbm, pol_ref, out_ref, acc_ref,
                 cp(nxt % 2, nxt).start()
 
             cp(slot, k).wait()
-            lit_words = litw_ref[:, pl.dslice(k * kw, kw)]
-            acc_ref[...] = _packed_viol_block(lit_words, inc_scr[slot],
+            acc_ref[...] = _packed_viol_block(litw_ref[k], inc_scr[slot],
                                               acc_ref[...], kw)
             return carry
 
@@ -210,7 +213,7 @@ def clause_eval_call(lit0, inc_t, *, bt, ct, kt, interpret):
         out_specs=pl.BlockSpec((bt, ct), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bt, ct), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(lit0, inc_t)
@@ -233,7 +236,7 @@ def tm_infer_call(lit0, inc_t, pol, *, bt, ct, kt, interpret):
         out_specs=pl.BlockSpec((bt, m), lambda i, j, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bt, ct), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(lit0, inc_t, pol)
@@ -255,16 +258,16 @@ def clause_eval_packed_call(litw, incw_t, *, bt, ct, kt, interpret):
         partial(clause_eval_packed_kernel, kw=kw),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bt, kw), lambda i, j, k: (i, k)),
+            pl.BlockSpec((None, bt, kw), lambda i, j, k: (k, i, 0)),
             pl.BlockSpec((kw, ct), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((bt, ct), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bt, ct), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(litw, incw_t)
+    )(word_chunks(litw, kw), incw_t)
 
 
 def tm_infer_planes_call(litw, incw_t, pol, *, bt, ct, kt, interpret):
@@ -280,22 +283,23 @@ def tm_infer_planes_call(litw, incw_t, pol, *, bt, ct, kt, interpret):
     m = pol.shape[1]
     if lw % kw:
         raise ValueError(f"word rows {lw} not divisible by kt/32={kw}")
+    nk = lw // kw
     grid = (b // bt, c // ct)
     return pl.pallas_call(
-        partial(tm_infer_planes_kernel, kw=kw, nk=lw // kw),
+        partial(tm_infer_planes_kernel, kw=kw, nk=nk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bt, lw), lambda i, j: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((nk, bt, kw), lambda i, j: (0, i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((ct, m), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((bt, m), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bt, ct), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(litw, incw_t, pol)
+    )(word_chunks(litw, kw), incw_t, pol)
 
 
 def tm_infer_packed_call(litw, incw_t, pol, *, bt, ct, kt, interpret):
@@ -311,14 +315,14 @@ def tm_infer_packed_call(litw, incw_t, pol, *, bt, ct, kt, interpret):
         partial(tm_infer_packed_kernel, kw=kw),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bt, kw), lambda i, j, k: (i, k)),
+            pl.BlockSpec((None, bt, kw), lambda i, j, k: (k, i, 0)),
             pl.BlockSpec((kw, ct), lambda i, j, k: (k, j)),
             pl.BlockSpec((ct, m), lambda i, j, k: (j, 0)),
         ],
         out_specs=pl.BlockSpec((bt, m), lambda i, j, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bt, ct), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(litw, incw_t, pol)
+    )(word_chunks(litw, kw), incw_t, pol)

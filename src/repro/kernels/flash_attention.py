@@ -39,8 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -150,7 +148,7 @@ def _flash_fwd_raw(q, k, v, causal, window, softcap, bq, bk, interp):
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
     )(qf, kf, vf)
@@ -286,7 +284,7 @@ def _flash_bwd_raw(q, k, v, out, lse, dout, causal, window, softcap,
                    jax.ShapeDtypeStruct((b * h, sp, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
     )(qf, kf, vf, dof, lse, dd)
@@ -306,7 +304,7 @@ def _flash_bwd_raw(q, k, v, out, lse, dout, causal, window, softcap,
         out_specs=pl.BlockSpec((1, bq, d), lambda bh, iq, ik: (bh, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sp, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
     )(qf, kf, vf, dof, lse, dd)

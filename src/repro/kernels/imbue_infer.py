@@ -1,6 +1,6 @@
 """Pallas TPU kernel for the IMBUE *analog* inference pipeline.
 
-Faithful current-domain semantics (DESIGN.md §2): per 32-cell column KCL
+Faithful current-domain semantics (paper §II): per 32-cell column KCL
 current -> CSA threshold -> AND across a clause's columns -> polarity
 matmul.  Unlike the digital kernel, the threshold is applied per column
 (the analog architecture cannot see the total violation count, only each
@@ -14,8 +14,8 @@ block into the [bt, M] class-sum output.
 
 The narrow (width=32) contraction underutilizes the 128-wide MXU by design
 — it emulates the paper's partial-clause sensing exactly.  The digital
-kernel in ``clause_eval.py`` is the full-width variant; the §Perf log
-quantifies the gap.
+kernel in ``clause_eval.py`` is the full-width variant; the gap has not
+been measured on the chip yet.
 """
 
 from __future__ import annotations
@@ -27,9 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 from repro.kernels.bitpack import (WORD, unpack_words_f32,
-                                   unpack_words_f32_cols)
+                                   unpack_words_f32_cols, word_chunks)
 
 
 def imbue_infer_kernel(i_ref_ref, v_drive_ref, lit1_ref, g_t_ref, leak_t_ref,
@@ -132,6 +131,11 @@ def imbue_infer_planes_kernel(*refs, width, cols_per_block, nk, has_dev):
     kernels.  Word-padded columns past ``l_valid`` would otherwise
     reconstruct as HRS cells (the f32 path zero-pads them away), so an
     in-kernel validity mask zeroes their ``g``/``leak`` contributions.
+
+    Grid is (replica, B block, C block): replica ``r`` reads its own
+    ``[L, C]`` slab of the ``[R, L, C]`` deviation stack, so a whole
+    replica stack is one kernel call (a vmap would instead batch the
+    ANY-space operand, which Mosaic cannot lower).
     """
     if has_dev:
         (scal_ref, litw_ref, incw_hbm, dev_hbm, pol_ref,
@@ -139,7 +143,8 @@ def imbue_infer_planes_kernel(*refs, width, cols_per_block, nk, has_dev):
     else:
         scal_ref, litw_ref, incw_hbm, pol_ref, out_ref, and_ref = refs
         dev_hbm = None
-    j = pl.program_id(1)
+    r_idx = pl.program_id(0)
+    j = pl.program_id(2)
 
     i_ref = scal_ref[0]
     v_read = scal_ref[1]
@@ -162,14 +167,13 @@ def imbue_infer_planes_kernel(*refs, width, cols_per_block, nk, has_dev):
         r = r_nom if dev_tile is None else r_nom + dev_tile
         # Mask word-padding columns (>= l_valid): the f32 path zero-pads
         # their g/leak rows; reconstruction must not resurrect them.
-        row = jax.lax.broadcasted_iota(jnp.float32, (kt, ct), 0)
-        valid = (k * kt).astype(jnp.float32) + row < l_valid
+        row = jax.lax.broadcasted_iota(jnp.int32, (kt, ct), 0)
+        valid = (k * kt + row).astype(jnp.float32) < l_valid
         g = jnp.where(valid, 1.0 / (series_factor * r), 0.0)
         leak_nom = jnp.where(bits_inc > 0.5, leak_inc, leak_exc)
         leak = jnp.where(valid, leak_nom * (r_nom / r), 0.0)
 
-        lit_words = litw_ref[:, pl.dslice(k * kw, kw)]
-        bits = unpack_words_f32(lit_words, n_bits=kt)           # [bt, kt]
+        bits = unpack_words_f32(litw_ref[k], n_bits=kt)         # [bt, kt]
         v_drive = (1.0 - bits) * v_read
         for w in range(cols_per_block):
             lo, hi = w * width, (w + 1) * width
@@ -187,7 +191,8 @@ def imbue_infer_planes_kernel(*refs, width, cols_per_block, nk, has_dev):
                 inc_scr.at[slot], inc_sem.at[slot])]
             if has_dev:
                 cps.append(pltpu.make_async_copy(
-                    dev_hbm.at[pl.dslice(k * kt, kt), pl.dslice(j * ct, ct)],
+                    dev_hbm.at[r_idx, pl.dslice(k * kt, kt),
+                               pl.dslice(j * ct, ct)],
                     dev_scr.at[slot], dev_sem.at[slot]))
             return cps
 
@@ -233,16 +238,17 @@ def imbue_infer_planes_kernel(*refs, width, cols_per_block, nk, has_dev):
 def imbue_infer_planes_call(litw, incw_t, dev_t, pol, v_ref, v_read, *,
                             width, r_div, r_lrs, r_hrs, leak_inc, leak_exc,
                             series_factor, l_valid, bt, ct, kt, interpret):
-    """``[B, L/32] -> [B, M]`` analog class sums from packed literals AND
-    a plane-packed conductance stack.
+    """``[B, L/32] -> [R, B, M]`` analog class sums from packed literals
+    AND a plane-packed conductance stack.
 
     ``incw_t`` is the transposed include-index bitplane ``[Lw, C]``
-    uint32 (bit ``j`` of word row ``w`` = literal ``32*w + j``);
-    ``dev_t`` is the transposed additive deviation plane ``[L, C]`` f32
-    or None for a nominal (index-only) stack.  ``kt`` counts bits and
-    must be a multiple of both ``width`` and 32.  The K dimension is
-    streamed *inside* the kernel with double-buffered HBM->VMEM copies,
-    so the grid is only (B, C) blocks.
+    uint32 (bit ``j`` of word row ``w`` = literal ``32*w + j``), shared
+    by every replica; ``dev_t`` is the transposed additive deviation
+    stack ``[R, L, C]`` f32, or None for a nominal (index-only) chip
+    (then ``R == 1``).  ``kt`` counts bits and must be a multiple of
+    both ``width`` and 32.  The K dimension is streamed *inside* the
+    kernel with double-buffered HBM->VMEM copies, so the grid is
+    (R, B, C) blocks.
     """
     if kt % width:
         raise ValueError(f"kt={kt} must be a multiple of width={width}")
@@ -258,10 +264,12 @@ def imbue_infer_planes_call(litw, incw_t, dev_t, pol, v_ref, v_read, *,
     if lw % kw:
         raise ValueError(f"word rows {lw} not divisible by kt/32={kw}")
     has_dev = dev_t is not None
-    if has_dev and dev_t.shape != (lw * WORD, c):
-        raise ValueError(f"dev plane {dev_t.shape} != {(lw * WORD, c)}")
+    if has_dev and dev_t.shape[1:] != (lw * WORD, c):
+        raise ValueError(f"dev planes {dev_t.shape[1:]} != "
+                         f"{(lw * WORD, c)}")
+    n_rep = dev_t.shape[0] if has_dev else 1
     nk = lw // kw
-    grid = (b // bt, c // ct)
+    grid = (n_rep, b // bt, c // ct)
     kern = partial(imbue_infer_planes_kernel, width=width,
                    cols_per_block=kt // width, nk=nk, has_dev=has_dev)
     scal = jnp.asarray([v_ref / r_div, v_read, r_lrs, r_hrs, leak_inc,
@@ -269,25 +277,26 @@ def imbue_infer_planes_call(litw, incw_t, dev_t, pol, v_ref, v_read, *,
                        dtype=jnp.float32)
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),                # scalars
-        pl.BlockSpec((bt, lw), lambda i, j: (i, 0)),          # literal words
-        pl.BlockSpec(memory_space=pltpu.ANY),                 # include plane
+        pl.BlockSpec((nk, bt, kw),                            # literal words
+                     lambda r, i, j: (0, i, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),                    # include plane
     ]
-    operands = [scal, litw]
+    operands = [scal, word_chunks(litw, kw)]
     if has_dev:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))  # dev plane
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))    # dev planes
         operands += [incw_t, dev_t, pol]
     else:
         operands += [incw_t, pol]
-    in_specs.append(pl.BlockSpec((ct, m), lambda i, j: (j, 0)))  # pol
+    in_specs.append(pl.BlockSpec((ct, m), lambda r, i, j: (j, 0)))  # pol
     return pl.pallas_call(
         kern,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bt, m), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
+        out_specs=pl.BlockSpec((None, bt, m), lambda r, i, j: (r, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_rep, b, m), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bt, ct), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
 
@@ -321,7 +330,7 @@ def imbue_infer_call(v_drive, lit1, g_t, leak_t, pol, v_ref, *,
         out_specs=pl.BlockSpec((bt, m), lambda i, j, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bt, ct), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray([v_ref / r_div], dtype=jnp.float32), v_drive, lit1, g_t,
@@ -356,7 +365,8 @@ def imbue_infer_packed_call(litw, g_t, leak_t, pol, v_ref, v_read, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),            # [i_ref, v_read]
-            pl.BlockSpec((bt, kw), lambda i, j, k: (i, k)),   # literal words
+            pl.BlockSpec((None, bt, kw),                      # literal words
+                         lambda i, j, k: (k, i, 0)),
             pl.BlockSpec((kt, ct), lambda i, j, k: (k, j)),   # g_t
             pl.BlockSpec((kt, ct), lambda i, j, k: (k, j)),   # leak_t
             pl.BlockSpec((ct, m), lambda i, j, k: (j, 0)),    # pol
@@ -364,8 +374,8 @@ def imbue_infer_packed_call(litw, g_t, leak_t, pol, v_ref, v_read, *,
         out_specs=pl.BlockSpec((bt, m), lambda i, j, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bt, ct), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(jnp.asarray([v_ref / r_div, v_read], dtype=jnp.float32), litw, g_t,
-      leak_t, pol)
+    )(jnp.asarray([v_ref / r_div, v_read], dtype=jnp.float32),
+      word_chunks(litw, kw), g_t, leak_t, pol)

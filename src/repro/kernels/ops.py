@@ -432,6 +432,49 @@ def imbue_class_sums_stack_packed(
     return jax.vmap(one)(r_stack, keys)
 
 
+def _read_dev(plane_index: jax.Array, plane_dev: jax.Array | None,
+              key: jax.Array | None, vcfg, l_valid: int):
+    """The additive deviation plane ``[C, L]`` one read cycle senses:
+    the programmed ``plane_dev`` plus, with a ``key`` and C2C on, a fresh
+    C2C draw (``apply_c2c(key, r_nom + dev, include, vcfg) - r_nom``).
+    None for a nominal chip read without C2C."""
+    from repro.core.variations import HRS_MEAN_OHM, LRS_MEAN_OHM, apply_c2c
+    if key is None or not vcfg.c2c:
+        return plane_dev
+    include = bitpack.unpack_bits(plane_index, l_valid).astype(bool)
+    r_nom = jnp.where(include, LRS_MEAN_OHM, HRS_MEAN_OHM)
+    r = r_nom if plane_dev is None else r_nom + plane_dev
+    return apply_c2c(key, r, include, vcfg) - r_nom
+
+
+def _planes_sums(litw, plane_index, devs, icfg, cfg, *, l_valid, bt, ct, kt,
+                 interpret):
+    """One plane-packed kernel call: ``devs`` ``[R, C, L]`` (or None for
+    a nominal chip, ``R = 1``) -> ``[R, B, M]`` class sums."""
+    from repro.core.variations import (HRS_MEAN_OHM, I_LEAK_EXCLUDE,
+                                       I_LEAK_INCLUDE, LRS_MEAN_OHM)
+    interp = (not _on_tpu()) if interpret is None else interpret
+    kw = kt // bitpack.WORD
+    b = litw.shape[0]
+    litw_p = _pad_to(_pad_to(litw.astype(jnp.uint32), 0, bt), 1, kw)
+    incw_t = _pad_to(_pad_to(plane_index.astype(jnp.uint32), 0, ct),
+                     1, kw).T
+    dev_t = (None if devs is None else
+             _pad_to(_pad_to(devs.astype(jnp.float32), 1, ct), 2, kt
+                     ).transpose(0, 2, 1))
+    pol = polarity_matrix(cfg)
+    pol = pol * _nonempty_from_packed(
+        plane_index)[:, None].astype(jnp.float32)
+    pol = _pad_to(pol, 0, ct)
+    out = _ai.imbue_infer_planes_call(
+        litw_p, incw_t, dev_t, pol, icfg.reference_voltage(), icfg.v_read,
+        width=icfg.width, r_div=icfg.r_divider, r_lrs=LRS_MEAN_OHM,
+        r_hrs=HRS_MEAN_OHM, leak_inc=I_LEAK_INCLUDE,
+        leak_exc=I_LEAK_EXCLUDE, series_factor=icfg.series_factor,
+        l_valid=l_valid, bt=bt, ct=ct, kt=kt, interpret=interp)
+    return out[:, :b, :cfg.n_classes]
+
+
 @partial(jax.jit, static_argnames=("icfg", "cfg", "vcfg", "l_valid", "bt",
                                    "ct", "kt", "interpret"))
 def imbue_class_sums_planes(
@@ -458,40 +501,16 @@ def imbue_class_sums_planes(
     kernel masks word-padding columns that the dense path zero-pads.
 
     C2C noise (``key`` + ``vcfg.c2c``) is drawn per read in jnp before
-    the kernel: the deviation plane becomes
-    ``apply_c2c(key, r_nom + dev, include, vcfg) - r_nom``.  The CSA
-    offset is NOT modeled (scalar reference), exactly like the dense
-    analog kernels — capability selection routes those reads elsewhere.
+    the kernel (:func:`_read_dev`).  The CSA offset is NOT modeled
+    (scalar reference), exactly like the dense analog kernels —
+    capability selection routes those reads elsewhere.
     """
-    from repro.core.variations import (HRS_MEAN_OHM, I_LEAK_EXCLUDE,
-                                       I_LEAK_INCLUDE, LRS_MEAN_OHM,
-                                       VariationConfig, apply_c2c)
+    from repro.core.variations import VariationConfig
     vcfg = vcfg or VariationConfig.nominal()
-    interp = (not _on_tpu()) if interpret is None else interpret
-    kw = kt // bitpack.WORD
-    b = litw.shape[0]
-    dev = plane_dev
-    if key is not None and vcfg.c2c:
-        include = bitpack.unpack_bits(plane_index, l_valid).astype(bool)
-        r_nom = jnp.where(include, LRS_MEAN_OHM, HRS_MEAN_OHM)
-        r = r_nom if dev is None else r_nom + dev
-        dev = apply_c2c(key, r, include, vcfg) - r_nom
-    litw_p = _pad_to(_pad_to(litw.astype(jnp.uint32), 0, bt), 1, kw)
-    incw_t = _pad_to(_pad_to(plane_index.astype(jnp.uint32), 0, ct),
-                     1, kw).T
-    dev_t = (None if dev is None else
-             _pad_to(_pad_to(dev.astype(jnp.float32), 0, ct), 1, kt).T)
-    pol = polarity_matrix(cfg)
-    pol = pol * _nonempty_from_packed(
-        plane_index)[:, None].astype(jnp.float32)
-    pol = _pad_to(pol, 0, ct)
-    out = _ai.imbue_infer_planes_call(
-        litw_p, incw_t, dev_t, pol, icfg.reference_voltage(), icfg.v_read,
-        width=icfg.width, r_div=icfg.r_divider, r_lrs=LRS_MEAN_OHM,
-        r_hrs=HRS_MEAN_OHM, leak_inc=I_LEAK_INCLUDE,
-        leak_exc=I_LEAK_EXCLUDE, series_factor=icfg.series_factor,
-        l_valid=l_valid, bt=bt, ct=ct, kt=kt, interpret=interp)
-    return out[:b, :cfg.n_classes]
+    dev = _read_dev(plane_index, plane_dev, key, vcfg, l_valid)
+    return _planes_sums(litw, plane_index, None if dev is None else dev[None],
+                        icfg, cfg, l_valid=l_valid, bt=bt, ct=ct, kt=kt,
+                        interpret=interpret)[0]
 
 
 @partial(jax.jit, static_argnames=("icfg", "cfg", "vcfg", "l_valid",
@@ -517,28 +536,28 @@ def imbue_class_sums_stack_planes(
     deviation plane is per-replica (each chip drew its own D2D noise /
     carries its own fault overlay) or None for a nominal stack.  Noise
     semantics match :func:`imbue_class_sums_stack_packed`: one fresh C2C
-    draw per replica per read from the split of ``key``.  A nominal
-    stack with no C2C read is ONE kernel dispatch broadcast over R —
+    draw per replica per read from the split of ``key``.  The whole
+    stack is ONE kernel call with a replica grid axis; a nominal stack
+    with no C2C read is one single-replica call broadcast over R —
     replicas are bit-identical by construction.
     """
     from repro.core.variations import VariationConfig
     vcfg = vcfg or VariationConfig.nominal()
-
-    def one(dev_r, k):
-        return imbue_class_sums_planes(
-            litw, plane_index, dev_r, icfg, cfg, k, vcfg=vcfg,
-            l_valid=l_valid, bt=bt, ct=ct, kt=kt, interpret=interpret)
-
-    c2c = key is not None and vcfg.c2c
-    if plane_dev is None and not c2c:
-        out = one(None, None)
-        return jnp.broadcast_to(out, (n_replicas,) + out.shape)
-    keys = (jax.random.split(key, n_replicas) if key is not None else None)
-    if plane_dev is None:
-        return jax.vmap(lambda k: one(None, k))(keys)
-    if keys is None:
-        return jax.vmap(lambda d: one(d, None))(plane_dev)
-    return jax.vmap(one)(plane_dev, keys)
+    opts = dict(l_valid=l_valid, bt=bt, ct=ct, kt=kt, interpret=interpret)
+    if key is not None and vcfg.c2c:
+        keys = jax.random.split(key, n_replicas)
+        if plane_dev is None:
+            devs = jax.vmap(lambda k: _read_dev(
+                plane_index, None, k, vcfg, l_valid))(keys)
+        else:
+            devs = jax.vmap(lambda d, k: _read_dev(
+                plane_index, d, k, vcfg, l_valid))(plane_dev, keys)
+    elif plane_dev is None:
+        out = _planes_sums(litw, plane_index, None, icfg, cfg, **opts)
+        return jnp.broadcast_to(out, (n_replicas,) + out.shape[1:])
+    else:
+        devs = plane_dev
+    return _planes_sums(litw, plane_index, devs, icfg, cfg, **opts)
 
 
 @partial(jax.jit, static_argnames=("bt", "ct", "kt", "interpret"))

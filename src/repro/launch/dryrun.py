@@ -36,7 +36,7 @@ from repro.launch.mesh import make_production_mesh, rules_for  # noqa: E402
 from repro.models import transformer as tf  # noqa: E402
 from repro.optim.optimizers import OptimizerConfig, make_optimizer  # noqa: E402
 from repro.roofline.analysis import (analyze_compiled,  # noqa: E402
-                                     collective_bytes, xla_cost_dict)
+                                     collective_bytes)
 from repro.train.train_step import make_train_step  # noqa: E402
 
 
@@ -118,7 +118,7 @@ def lower_cell(arch: str, shape: str, mesh, *, verbose=True):
         t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = xla_cost_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     rec = analyze_compiled(arch, shape, mesh, cfg, compiled, cost, mem,
                            coll)
@@ -142,8 +142,7 @@ def lower_tm_cell(arch: str, shape: str, mesh, *, verbose=True):
     """The paper's TM workload through the same dry-run machinery."""
     from repro.configs.imbue_tm import tm_config
     from repro.core import tm_distributed as tmd
-    from repro.roofline.analysis import (HBM_BW, ICI_BW, PEAK_FLOPS,
-                                         HloCost)
+    from repro.roofline.analysis import TARGET_KIND, HloCost, peaks
 
     cfg = tmd.pad_clauses_for_mesh(tm_config(arch), mesh)
     kind, batch = TM_SHAPES[shape]
@@ -197,9 +196,10 @@ def lower_tm_cell(arch: str, shape: str, mesh, *, verbose=True):
     for v in mesh.shape.values():
         n_dev *= v
     model_flops = mult * batch * c * l * active
-    compute_s = hc.flops / PEAK_FLOPS
-    memory_s = hc.bytes / HBM_BW
-    coll_s = hc.collective_bytes / ICI_BW
+    pk = peaks(TARGET_KIND)
+    compute_s = hc.flops / pk.bf16_flops
+    memory_s = hc.bytes / pk.hbm_bw
+    coll_s = hc.collective_bytes / pk.ici_bw
     dominant = max((("compute", compute_s), ("memory", memory_s),
                     ("collective", coll_s)), key=lambda kv: kv[1])[0]
     rec = {
@@ -279,4 +279,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

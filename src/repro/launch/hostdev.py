@@ -34,14 +34,10 @@ def parse_host_devices(argv: Sequence[str]) -> Optional[int]:
 
 def force_host_devices(argv: Sequence[str]) -> None:
     """Apply ``--host-devices`` from ``argv`` to XLA_FLAGS (idempotent
-    no-op when the flag is absent/malformed).  Also defaults to the
-    partitionable threefry generator: sharded noise draws only match
-    single-device bits with the counter-based, placement-independent
-    PRNG."""
+    no-op when the flag is absent/malformed)."""
     n = parse_host_devices(argv)
     if n is None:
         return
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={n}")
-    os.environ.setdefault("JAX_THREEFRY_PARTITIONABLE", "1")

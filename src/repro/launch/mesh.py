@@ -9,10 +9,18 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.distributed.sharding import ShardingRules
 from repro.models.config import ModelConfig
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: shardings propagate
+    through gathers and reshapes (GSPMD) instead of demanding an explicit
+    ``out_sharding`` at each op, which is what the serving and TM paths
+    are written against."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -20,17 +28,17 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     256 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2) -> Mesh:
     """Small mesh for multi-device CPU tests (8 forced host devices)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_pipeline_mesh(stages: int = 4) -> Mesh:
     """Pipeline-parallel demo mesh (see distributed/pipeline.py)."""
-    return jax.make_mesh((stages,), ("pipe",))
+    return _auto_mesh((stages,), ("pipe",))
 
 
 def make_replica_mesh(n_replica: int, n_batch: int = 1) -> Mesh:
@@ -40,7 +48,7 @@ def make_replica_mesh(n_replica: int, n_batch: int = 1) -> Mesh:
     (one shard of chips per device); the optional ``batch`` axis splits
     request rows for data-parallel reads.  Consumed by
     ``ReplicaPool.shard`` via ``distributed.sharding.replica_rules``."""
-    return jax.make_mesh((n_replica, n_batch), ("replica", "batch"))
+    return _auto_mesh((n_replica, n_batch), ("replica", "batch"))
 
 
 def parse_mesh_spec(spec: str) -> Mesh:

@@ -19,9 +19,12 @@ walks the *optimized, SPMD-partitioned* HLO text itself:
 Because the module is already partitioned, all shapes are per-device:
 ``compute_s = flops / peak_flops`` directly (no further /chips).
 
-Hardware constants (TPU v5e-class, per the brief): 197 TFLOP/s bf16,
-819 GB/s HBM, ~50 GB/s/link ICI (x4 links usable per chip per axis-pair
-in a 2D torus; we use 1 link per collective direction — conservative).
+Hardware peaks come from :data:`PEAKS`, keyed by JAX's ``device_kind``
+with their published source; a device that is not in the table is an
+error, never a silent v5e default.  Code that runs beside a chip keys
+by that chip's ``device_kind``; only host-side models with no chip
+attached (the dry-run, the kernel bench's analytic roofline off a TPU)
+name their target chip, :data:`TARGET_KIND`.
 """
 
 from __future__ import annotations
@@ -30,9 +33,34 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks of one TPU generation."""
+
+    bf16_flops: float        # FLOP/s
+    hbm_bw: float            # bytes/s
+    ici_bw: float            # bytes/s per link (one link per direction)
+    source: str
+
+
+PEAKS: Dict[str, Peaks] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM
+    # at 819 GB/s, 1,600 Gbit/s ICI per chip over 4 links (50 GB/s each).
+    "TPU v5 lite": Peaks(bf16_flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                         source="Google Cloud documentation, TPU v5e"),
+}
+TARGET_KIND = "TPU v5 lite"      # the deployment chip the dry-run models
+
+
+def peaks(device_kind: str) -> Peaks:
+    """Peaks of ``device_kind`` (``jax.devices()[0].device_kind``)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add it "
+            f"to repro.roofline.analysis.PEAKS with its source "
+            f"(known: {sorted(PEAKS)})") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -44,17 +72,6 @@ _DTYPE_BYTES = {
 _SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
 _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                 "collective-permute")
-
-
-def xla_cost_dict(cost):
-    """Normalize ``compiled.cost_analysis()`` across jax versions.
-
-    jax <= 0.4.x returns a single-element list of dicts; newer releases
-    return the dict directly.  Returns a dict or None.
-    """
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else None
-    return cost
 
 
 def _shape_bytes(dtype: str, dims: str) -> int:
@@ -322,7 +339,8 @@ def model_flops_per_step(cfg, params_abs, kind: str, global_batch: int,
 
 def analyze_compiled(arch, shape, mesh, cfg, compiled, cost, mem, coll,
                      params_abs=None) -> dict:
-    """One §Roofline record (all quantities PER DEVICE)."""
+    """One §Roofline record (all quantities PER DEVICE) for the dry-run's
+    target chip, :data:`TARGET_KIND`."""
     from repro.launch.shapes import SHAPES
     spec = SHAPES[shape]
     hlo_cost = HloCost(compiled.as_text())
@@ -337,9 +355,10 @@ def analyze_compiled(arch, shape, mesh, cfg, compiled, cost, mem, coll,
     flops_dev = hlo_cost.flops
     bytes_dev = hlo_cost.bytes
     coll_dev = hlo_cost.collective_bytes
-    compute_s = flops_dev / PEAK_FLOPS
-    memory_s = bytes_dev / HBM_BW
-    coll_s = coll_dev / ICI_BW
+    pk = peaks(TARGET_KIND)
+    compute_s = flops_dev / pk.bf16_flops
+    memory_s = bytes_dev / pk.hbm_bw
+    coll_s = coll_dev / pk.ici_bw
     dominant = max((("compute", compute_s), ("memory", memory_s),
                     ("collective", coll_s)), key=lambda kv: kv[1])[0]
     mesh_name = "x".join(f"{k}={v}" for k, v in mesh.shape.items())

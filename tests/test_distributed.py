@@ -25,11 +25,6 @@ def run_devices(code: str, n: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    # Legacy threefry is not sharding-invariant: identical keys yield
-    # different bits once an operand is sharded, breaking the bitwise
-    # sharded==single assertions below.  The partitionable generator is
-    # counter-based and placement-independent.
-    env["JAX_THREEFRY_PARTITIONABLE"] = "1"
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
                          timeout=900)

@@ -5,7 +5,7 @@ to ``tm.forward`` — but if the *reference itself* drifted (a semantics
 change in ``core/tm.py``, a jax upgrade changing a kernel's rounding,
 all backends drifting together), the matrix would stay green while
 every committed result silently changed.  This suite closes that hole:
-``tests/golden/backends_v3.json`` carries the class sums + preds of a
+``tests/golden/backends_v4.json`` carries the class sums + preds of a
 fixed seed/model/batch, and EVERY registered backend must reproduce
 them bit-for-bit at ``VariationConfig.nominal()``.  v2 (ISSUE 6) adds
 the coalesced family (``coalesced-pallas``/``coalesced-pallas-packed``
@@ -15,7 +15,9 @@ meta-test (``test_registry_coverage.py``) checks against the live
 registry, so registering a backend without golden coverage fails CI.
 v3 (ISSUE 9) adds the plane-packed states (``*_planes``) and the
 ``analog-pallas-packed2``/``coalesced-pallas-packed2`` backends that
-serve from the LRS/HRS index bitplane.
+serve from the LRS/HRS index bitplane.  v4 is v3's schema regenerated
+under JAX 0.9's PRNG stream (the partitionable threefry generator), whose
+seeded inputs differ from the legacy stream v3 was written under.
 
 The golden inputs (include mask, request batch) are recreated from
 seeds and guarded by committed SHA-256 digests, so a failure is
@@ -52,7 +54,7 @@ from repro.core.variations import VariationConfig
 from repro.kernels import ops
 
 GOLDEN_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                           "golden", "backends_v3.json")
+                           "golden", "backends_v4.json")
 
 # Fixed golden workload.  Changing ANY of these constants invalidates
 # the committed file — regenerate in the same commit.

@@ -6,8 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.roofline.analysis import (HloCost, parse_computations,
-                                     xla_cost_dict)
+from repro.roofline.analysis import HloCost, parse_computations
 
 
 def _scan_fn(x, ws):
@@ -33,8 +32,8 @@ def compiled_pair():
 def test_xla_cost_analysis_undercounts_scan(compiled_pair):
     """The motivating bug: XLA counts the while body once."""
     cs, cu = compiled_pair
-    cost_s = xla_cost_dict(cs.cost_analysis())
-    cost_u = xla_cost_dict(cu.cost_analysis())
+    cost_s = cs.cost_analysis()
+    cost_u = cu.cost_analysis()
     assert cost_s["flops"] < cost_u["flops"] / 4
 
 
@@ -119,3 +118,33 @@ def test_dot_flops_with_batch_dims():
     comp = jax.jit(f).lower(a, b).compile()
     got = HloCost(comp.as_text()).flops
     assert got == pytest.approx(2 * 4 * 32 * 64 * 16, rel=1e-6)
+
+
+def test_peaks_keyed_by_device_kind():
+    """Peaks come from a table keyed by ``device_kind`` with a source; a
+    chip that is not in it is an error, not a silent v5e default."""
+    from repro.roofline.analysis import TARGET_KIND, peaks
+    v5e = peaks(TARGET_KIND)
+    assert (v5e.bf16_flops, v5e.hbm_bw) == (197e12, 819e9)
+    assert v5e.source
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks("cpu")
+
+
+def test_kernel_roofline_keys_by_the_attached_chip(monkeypatch):
+    """Off a TPU the kernel bench models the target chip; beside a TPU
+    it takes that chip's peaks, and an unlisted chip raises."""
+    import importlib.util
+    import types
+    from pathlib import Path
+
+    from repro.roofline.analysis import TARGET_KIND
+    path = Path(__file__).resolve().parents[1] / "benchmarks/kernel_bench.py"
+    spec = importlib.util.spec_from_file_location("kernel_bench", path)
+    kb = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(kb)
+    assert kb.roofline_kind() == TARGET_KIND
+    unlisted = types.SimpleNamespace(platform="tpu", device_kind="TPU v99")
+    monkeypatch.setattr(kb.jax, "devices", lambda *a: [unlisted])
+    with pytest.raises(ValueError, match="no published peaks"):
+        kb.kernel_roofline(128, 128, 128, bt=128, ct=128, kt=128)

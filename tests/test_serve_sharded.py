@@ -72,9 +72,6 @@ def run_devices(code: str, n: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    # Placement-independent PRNG bits: the sharded==single bitwise
-    # assertions need the counter-based partitionable generator.
-    env["JAX_THREEFRY_PARTITIONABLE"] = "1"
     src = textwrap.dedent(PROLOGUE) + textwrap.dedent(code)
     out = subprocess.run(
         [sys.executable, "-c", src],

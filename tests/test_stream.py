@@ -435,7 +435,6 @@ def test_streamed_equals_offline_on_sharded_mesh():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env["JAX_THREEFRY_PARTITIONABLE"] = "1"
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
                          timeout=900)
