@@ -1,0 +1,7 @@
+"""90th percentile latency of every request due in the window (ms)."""
+
+from readings import latency_ms
+
+
+def read(run):
+    return latency_ms(run, 90)
