@@ -86,6 +86,7 @@ from typing import Callable, Deque, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import api
 from repro.api.registry import CAP_FUSED_KERNEL, CAP_PACKED_IO
@@ -97,7 +98,8 @@ from repro.serve.batching import (QOS_BULK, Batch, BatcherConfig,
                                   DynamicBatcher, QueueFull,
                                   pack_request_np, validate_qos)
 from repro.serve.health import HealthConfig, HealthProbe
-from repro.serve.metrics import RequestRecord, ServeMetrics, hardware_figures
+from repro.serve.metrics import (SPANS, RequestRecord, ServeMetrics,
+                                 hardware_figures)
 from repro.serve.replica import ReplicaPool, RouterState, ensemble_vote, \
     program_replica_pool
 
@@ -252,6 +254,9 @@ class InFlight:
     :meth:`ServeEngine._collect` blocks on them."""
 
     batch: Batch
+    seq: int                         # dispatch sequence number: the
+                                     # ``batch`` arg of its spans and its
+                                     # dispatch_log entry
     sums: jax.Array                  # [bucket, M] device future
     preds: jax.Array                 # [bucket] device future
     replica: int                     # serving chip, or ENSEMBLE
@@ -404,6 +409,7 @@ class ServeEngine:
         self._taken: set = set()
         self._discard: set = set()
         self._blocked_s = 0.0           # cumulative block_until_ready time
+        self._n_dispatched = 0          # last dispatch sequence number
         # Live hot-swap state (ISSUE 7): the armed canary (None when
         # plain serving) and its deterministic traffic accumulator.
         self._canary: Optional[_Canary] = None
@@ -540,28 +546,31 @@ class ServeEngine:
         large buckets.  Per-class ``BatcherConfig`` depth limits reject
         a full class with :class:`QueueFull` without touching the other.
         """
-        validate_qos(qos)
-        if (self.ecfg.max_queue_depth is not None
-                and len(self.batcher) >= self.ecfg.max_queue_depth):
-            self.metrics.note_rejected(qos=qos)
-            raise QueueFull(
-                f"queue depth {len(self.batcher)} is at "
-                f"max_queue_depth={self.ecfg.max_queue_depth}; retry "
-                "after pump() or raise the limit")
-        class_depth = self.batcher.cfg.queue_depth_for(qos)
-        if (class_depth is not None
-                and self.batcher.depth(qos) >= class_depth):
-            self.metrics.note_rejected(qos=qos)
-            raise QueueFull(
-                f"{qos} class depth {self.batcher.depth(qos)} is at its "
-                f"per-class limit {class_depth}; retry after pump() or "
-                "raise the limit")
-        rid = self._next_rid
-        self._next_rid += 1
-        self.batcher.submit(rid, x, self.clock(), deadline_s=deadline_s,
-                            qos=qos)
-        self._submitted.append(rid)
-        return rid
+        with TraceAnnotation(SPANS["submit"]):
+            validate_qos(qos)
+            if (self.ecfg.max_queue_depth is not None
+                    and len(self.batcher) >= self.ecfg.max_queue_depth):
+                self.metrics.note_rejected(qos=qos)
+                raise QueueFull(
+                    f"queue depth {len(self.batcher)} is at "
+                    f"max_queue_depth={self.ecfg.max_queue_depth}; retry "
+                    "after pump() or raise the limit")
+            class_depth = self.batcher.cfg.queue_depth_for(qos)
+            if (class_depth is not None
+                    and self.batcher.depth(qos) >= class_depth):
+                self.metrics.note_rejected(qos=qos)
+                raise QueueFull(
+                    f"{qos} class depth {self.batcher.depth(qos)} is at its "
+                    f"per-class limit {class_depth}; retry after pump() or "
+                    "raise the limit")
+            rid = self._next_rid
+            self._next_rid += 1
+            t_pack = time.perf_counter()
+            self.batcher.submit(rid, x, self.clock(), deadline_s=deadline_s,
+                                qos=qos)
+            self.metrics.note_pack(time.perf_counter() - t_pack)
+            self._submitted.append(rid)
+            return rid
 
     def submit_many(self, xs: Sequence[np.ndarray], *,
                     deadline_s: Optional[float] = None,
@@ -598,16 +607,20 @@ class ServeEngine:
         it must resolve ``expired=True``, never dispatch late (the
         batcher's cut paths also reap internally, making the invariant
         hold for direct ``cut(force=True)`` callers)."""
-        self._prune_consumed()
-        served = 0
-        while True:
-            now = self.clock()
-            self._reap_expired(now)
-            batch = self.batcher.cut(now, force=force)
-            if batch is None:
-                return served
-            self._dispatch(batch)
-            served += batch.n_valid
+        with TraceAnnotation(SPANS["pump"]):
+            self._prune_consumed()
+            served = 0
+            while True:
+                with TraceAnnotation(SPANS["cut"]):
+                    now = self.clock()
+                    self._reap_expired(now)
+                    batch = self.batcher.cut(now, force=force)
+                if batch is None:
+                    break
+                self._dispatch(batch)
+                served += batch.n_valid
+            self._collect_ready()
+            return served
 
     def drain(self) -> List[Response]:
         """Force-serve everything queued; responses in submission order
@@ -664,6 +677,10 @@ class ServeEngine:
         engine collects inside ``_dispatch``; AsyncServeEngine
         overrides)."""
 
+    def _collect_ready(self) -> None:
+        """Collect the dispatches whose device work has finished, at the
+        end of a pump (no-op for the synchronous engine)."""
+
     # ------------------------------------------------------------ dispatch
 
     def _read_key(self) -> Optional[jax.Array]:
@@ -699,69 +716,73 @@ class ServeEngine:
         """Issue one batch's fused jit'd forward WITHOUT blocking on the
         result: JAX dispatch is asynchronous, so the returned
         :class:`InFlight` holds device futures."""
-        t_dispatch = self.clock()
-        # Packed batches already ARE the literal wire format (packed at
-        # submit); dense batches expand to literals on device.
-        lits = jnp.asarray(batch.x)
-        if not batch.packed:
-            lits = tm.literals(lits)
-        lits = self._shard_lits(lits)
-        key = self._read_key()
-        if self.selection.fell_back:
-            self.metrics.note_forward_fallback(
-                self.selection.fallback_reason)
-        canary = self._take_canary_turn()
-        if canary is not None:
-            # Canary dispatch: the candidate chip SERVES this batch, and
-            # the stable pool shadow-evaluates the same rows with the
-            # same read key — so argmax disagreement measures the model
-            # change, not a different noise draw.  The stable chip did a
-            # real read, so its router load counter still advances.
-            sums, preds = self._fwd(canary.state, lits, key,
-                                    self._mask_one, bt=batch.bucket)
+        self._n_dispatched += 1
+        seq = self._n_dispatched
+        with TraceAnnotation(SPANS["issue"], batch=seq, bucket=batch.bucket,
+                             rows=batch.n_valid):
+            t_dispatch = self.clock()
+            # Packed batches already ARE the literal wire format (packed at
+            # submit); dense batches expand to literals on device.
+            lits = jnp.asarray(batch.x)
+            if not batch.packed:
+                lits = tm.literals(lits)
+            lits = self._shard_lits(lits)
+            key = self._read_key()
+            if self.selection.fell_back:
+                self.metrics.note_forward_fallback(
+                    self.selection.fallback_reason)
+            canary = self._take_canary_turn()
+            if canary is not None:
+                # Canary dispatch: the candidate chip SERVES this batch, and
+                # the stable pool shadow-evaluates the same rows with the
+                # same read key — so argmax disagreement measures the model
+                # change, not a different noise draw.  The stable chip did a
+                # real read, so its router load counter still advances.
+                sums, preds = self._fwd(canary.state, lits, key,
+                                        self._mask_one, bt=batch.bucket)
+                if self.ecfg.routing == "ensemble":
+                    _, shadow = self._fwd(self.state, lits, key,
+                                          self._healthy_mask, bt=batch.bucket)
+                    for i in self.router.healthy_replicas():
+                        self.router.note_dispatch(i, batch.bucket)
+                else:
+                    stable = self.router.pick(self.ecfg.routing)
+                    _, shadow = self._fwd(self._slices[stable], lits, key,
+                                          self._mask_one, bt=batch.bucket)
+                    self.router.note_dispatch(stable, batch.bucket)
+                shadow_nbytes = (self._resident_full
+                                 if self.ecfg.routing == "ensemble"
+                                 else self._resident_slice)
+                return InFlight(batch=batch, seq=seq, sums=sums, preds=preds,
+                                replica=CANARY, t_dispatch=t_dispatch,
+                                t_issue=self.clock(),
+                                blocked_snapshot=self._blocked_s,
+                                version=canary.version, shadow_preds=shadow,
+                                resident_nbytes=_resident_model_nbytes(
+                                    canary.state, self.backend)
+                                + shadow_nbytes)
             if self.ecfg.routing == "ensemble":
-                _, shadow = self._fwd(self.state, lits, key,
-                                      self._healthy_mask, bt=batch.bucket)
+                sums, preds = self._fwd(self.state, lits, key,
+                                        self._healthy_mask, bt=batch.bucket)
+                replica = ENSEMBLE
+                # Only voting chips count as load: a quarantined chip's
+                # sums are computed in the fused dispatch but masked out of
+                # the vote, so it did not *serve* the batch.
                 for i in self.router.healthy_replicas():
                     self.router.note_dispatch(i, batch.bucket)
             else:
-                stable = self.router.pick(self.ecfg.routing)
-                _, shadow = self._fwd(self._slices[stable], lits, key,
-                                      self._mask_one, bt=batch.bucket)
-                self.router.note_dispatch(stable, batch.bucket)
-            shadow_nbytes = (self._resident_full
-                             if self.ecfg.routing == "ensemble"
-                             else self._resident_slice)
-            return InFlight(batch=batch, sums=sums, preds=preds,
-                            replica=CANARY, t_dispatch=t_dispatch,
+                replica = self.router.pick(self.ecfg.routing)
+                sums, preds = self._fwd(self._slices[replica], lits, key,
+                                        self._mask_one, bt=batch.bucket)
+                self.router.note_dispatch(replica, batch.bucket)
+            return InFlight(batch=batch, seq=seq, sums=sums, preds=preds,
+                            replica=replica, t_dispatch=t_dispatch,
                             t_issue=self.clock(),
                             blocked_snapshot=self._blocked_s,
-                            version=canary.version, shadow_preds=shadow,
-                            resident_nbytes=_resident_model_nbytes(
-                                canary.state, self.backend)
-                            + shadow_nbytes)
-        if self.ecfg.routing == "ensemble":
-            sums, preds = self._fwd(self.state, lits, key,
-                                    self._healthy_mask, bt=batch.bucket)
-            replica = ENSEMBLE
-            # Only voting chips count as load: a quarantined chip's
-            # sums are computed in the fused dispatch but masked out of
-            # the vote, so it did not *serve* the batch.
-            for i in self.router.healthy_replicas():
-                self.router.note_dispatch(i, batch.bucket)
-        else:
-            replica = self.router.pick(self.ecfg.routing)
-            sums, preds = self._fwd(self._slices[replica], lits, key,
-                                    self._mask_one, bt=batch.bucket)
-            self.router.note_dispatch(replica, batch.bucket)
-        return InFlight(batch=batch, sums=sums, preds=preds,
-                        replica=replica, t_dispatch=t_dispatch,
-                        t_issue=self.clock(),
-                        blocked_snapshot=self._blocked_s,
-                        version=self.pool.version,
-                        resident_nbytes=(self._resident_full
-                                         if replica == ENSEMBLE
-                                         else self._resident_slice))
+                            version=self.pool.version,
+                            resident_nbytes=(self._resident_full
+                                             if replica == ENSEMBLE
+                                             else self._resident_slice))
 
     def _take_canary_turn(self) -> Optional[_Canary]:
         """Deterministic traffic split: an accumulator hands ~fraction
@@ -785,46 +806,51 @@ class ServeEngine:
         snapshots) are subtracted, so a deep pipeline cannot claim its
         neighbours' blocked waits as overlap.  The remainder of this
         batch's device time shows up as its own blocked wait."""
-        t_wait0 = self.clock()
-        waits = (fl.sums, fl.preds) if fl.shadow_preds is None \
-            else (fl.sums, fl.preds, fl.shadow_preds)
-        jax.block_until_ready(waits)
-        t_done = self.clock()
-        blocked_elsewhere = self._blocked_s - fl.blocked_snapshot
-        overlapped = max(0.0, (t_wait0 - fl.t_issue) - blocked_elsewhere)
-        self._blocked_s += t_done - t_wait0
-        preds = np.asarray(fl.preds)
-        sums = np.asarray(fl.sums)
-        batch = fl.batch
-        if fl.shadow_preds is not None:       # canary batch: score the
-            shadow = np.asarray(fl.shadow_preds)  # stable pool's argmax
-            agree = int((preds[:batch.n_valid]       # on the valid rows
-                         == shadow[:batch.n_valid]).sum())
-            self.metrics.note_canary(batch.n_valid, agree)
+        with TraceAnnotation(SPANS["collect"], batch=fl.seq):
+            t_wait0 = self.clock()
+            waits = (fl.sums, fl.preds) if fl.shadow_preds is None \
+                else (fl.sums, fl.preds, fl.shadow_preds)
+            with TraceAnnotation(SPANS["block"]):
+                jax.block_until_ready(waits)
+            t_done = self.clock()
+            blocked_elsewhere = self._blocked_s - fl.blocked_snapshot
+            overlapped = max(0.0, (t_wait0 - fl.t_issue) - blocked_elsewhere)
+            self._blocked_s += t_done - t_wait0
+            preds = np.asarray(fl.preds)
+            sums = np.asarray(fl.sums)
+            batch = fl.batch
+            if fl.shadow_preds is not None:       # canary batch: score the
+                shadow = np.asarray(fl.shadow_preds)  # stable pool's argmax
+                agree = int((preds[:batch.n_valid]       # on the valid rows
+                             == shadow[:batch.n_valid]).sum())
+                self.metrics.note_canary(batch.n_valid, agree)
 
-        records = []
-        for row, req in enumerate(batch.requests):
-            if req.rid in self._discard:      # abandoned by a session
-                self._discard.discard(req.rid)  # reset; served + counted,
-            else:                               # never retained
-                self._results[req.rid] = Response(
-                    rid=req.rid, pred=int(preds[row]),
-                    class_sums=sums[row], replica=fl.replica,
-                    latency_s=t_done - req.t_enqueue,
-                    version=fl.version)
-            records.append(RequestRecord(
-                rid=req.rid, t_enqueue=req.t_enqueue,
-                t_dispatch=fl.t_dispatch, t_done=t_done,
-                bucket=batch.bucket, n_valid=batch.n_valid,
-                replica=fl.replica, version=fl.version, qos=req.qos))
-        # Pad rows (batch.n_padding of them) are dropped here by
-        # construction: only batch.requests rows produce Responses.
-        assert len(records) == batch.n_valid
-        self.metrics.record_batch(records, batch.bucket, batch.nbytes,
-                                  resident_nbytes=fl.resident_nbytes)
-        self.metrics.note_dispatch_timing(
-            pack_s=batch.pack_s, wait_s=t_done - t_wait0,
-            overlapped_s=overlapped)
+            records = []
+            for row, req in enumerate(batch.requests):
+                if req.rid in self._discard:      # abandoned by a session
+                    self._discard.discard(req.rid)  # reset; served + counted,
+                else:                               # never retained
+                    self._results[req.rid] = Response(
+                        rid=req.rid, pred=int(preds[row]),
+                        class_sums=sums[row], replica=fl.replica,
+                        latency_s=t_done - req.t_enqueue,
+                        version=fl.version)
+                records.append(RequestRecord(
+                    rid=req.rid, t_enqueue=req.t_enqueue,
+                    t_dispatch=fl.t_dispatch, t_done=t_done,
+                    bucket=batch.bucket, n_valid=batch.n_valid,
+                    replica=fl.replica, version=fl.version, qos=req.qos))
+            # Pad rows (batch.n_padding of them) are dropped here by
+            # construction: only batch.requests rows produce Responses.
+            assert len(records) == batch.n_valid
+            self.metrics.record_batch(records, batch.bucket, batch.nbytes,
+                                      resident_nbytes=fl.resident_nbytes)
+            self.metrics.note_dispatch(
+                fl.seq, fl.t_dispatch, batch.bucket, batch.n_valid,
+                fl.t_dispatch - batch.requests[0].t_enqueue)
+            self.metrics.note_dispatch_timing(
+                pack_s=batch.pack_s, wait_s=t_done - t_wait0,
+                overlapped_s=overlapped)
 
     # ------------------------------------------------------------ hot swap
 
@@ -1151,19 +1177,18 @@ class AsyncServeEngine(ServeEngine):
             self._collect(self._pending.popleft())
         self._pending.append(self._issue(batch))
 
-    def pump(self, force: bool = False) -> int:
-        served = super().pump(force)
-        # Opportunistically collect dispatches whose device work already
-        # finished: results land as early as the event loop allows, and
-        # host *idle* time between request arrivals is not misattributed
-        # as overlap (the in-flight window closes at the first pump
-        # after completion, not whenever the next batch forces a
-        # collect).  The overlap accounting therefore remains a
-        # host-side observation — exact under continuous load, an
-        # approximation when the engine sits idle between pumps.
+    def _collect_ready(self) -> None:
+        # Opportunistically collect, at every pump, dispatches whose
+        # device work already finished: results land as early as the
+        # event loop allows, and host *idle* time between request
+        # arrivals is not misattributed as overlap (the in-flight window
+        # closes at the first pump after completion, not whenever the
+        # next batch forces a collect).  The overlap accounting
+        # therefore remains a host-side observation — exact under
+        # continuous load, an approximation when the engine sits idle
+        # between pumps.
         while self._pending and self._is_ready(self._pending[0]):
             self._collect(self._pending.popleft())
-        return served
 
     @staticmethod
     def _is_ready(fl: InFlight) -> bool:

@@ -26,6 +26,27 @@ from repro.core.mapping import csa_count_packed
 from repro.core.tm import TMConfig
 from repro.serve.batching import QOS_BULK
 
+# Profiler spans of the serving path (``jax.profiler.TraceAnnotation``):
+# each lands on the profiler's host thread line, on the device trace's
+# clock, so every device idle gap can be put down to one of them.  With
+# no profiler running a span costs under a microsecond on a CPU host
+# (1-1.5 us with args).  None sits inside the jitted forward.
+SPANS: Dict[str, str] = {
+    "push": "stream.push",          # StreamSession.feed: ring buffer and
+                                    # thermometer encoding of the frames
+    "submit": "serve.submit",       # ServeEngine.submit: admission, wire
+                                    # packing, enqueue
+    "pump": "serve.pump",           # ServeEngine.pump: the whole call
+    "cut": "serve.cut",             # one pump iteration's expiry reap and
+                                    # batch cut (stack and pad)
+    "issue": "serve.issue",         # operand upload and the jitted call(s);
+                                    # args batch, bucket, rows
+    "collect": "serve.collect",     # results to Responses and records,
+                                    # metrics booking; arg batch
+    "block": "serve.block",         # inside collect: block_until_ready
+    "scan": "stream.collect",       # StreamServer: every session's take
+}
+
 
 @dataclasses.dataclass
 class RequestRecord:
@@ -98,11 +119,12 @@ class ServeMetrics:
         # so noise semantics differ from the preference.  Loud on purpose.
         self.forward_fallbacks: List[str] = []
         self.fallback_dispatches = 0
-        # Overlap accounting (async serving): per dispatch, how long the
-        # host spent packing/bucketing the batch, how long it *blocked*
-        # on the device at collection, and how much of the in-flight
-        # window was hidden behind other host work.  A synchronous
-        # engine collects immediately, so its overlapped_s stays ~0.
+        # Overlap accounting (async serving): how long the host spent
+        # packing requests at submit and stacking/padding each batch at
+        # its cut, how long each dispatch *blocked* on the device at
+        # collection, and how much of the in-flight window was hidden
+        # behind other host work.  A synchronous engine collects
+        # immediately, so its overlapped_s stays ~0.
         self.host_pack_s = 0.0
         self.device_wait_s = 0.0
         self.overlapped_s = 0.0
@@ -148,6 +170,17 @@ class ServeMetrics:
         # request's enqueue -> done span, so it includes queue wait:
         # the figure a streaming client feels.
         self.session_decisions: Dict[str, dict] = {}
+        # One entry per dispatch, booked with its batch:
+        # ``(batch, t_dispatch, bucket, rows, head_wait_s)`` on the
+        # engine clock.  ``batch`` is the engine's dispatch sequence
+        # number, also the ``batch`` arg of the dispatch's issue and
+        # collect spans; ``head_wait_s`` is how long the batch's oldest
+        # request queued before its dispatch.
+        self.dispatch_log: Deque[Tuple[int, float, int, int, float]] = \
+            deque(maxlen=self.DISPATCH_WINDOW)
+
+    # Dispatch log entries retained: about 200 s at 320 dispatches/s.
+    DISPATCH_WINDOW = 65536
 
     def note_forward_fallback(self, reason: str) -> None:
         """Record one dispatch served by a fallback backend."""
@@ -218,6 +251,16 @@ class ServeMetrics:
         """Record one chaos fault injection (``replicas`` None = all)."""
         self.fault_injections.append({"replicas": replicas})
 
+    def note_pack(self, pack_s: float) -> None:
+        """Account host time spent packing one request at submit."""
+        self.host_pack_s += max(0.0, pack_s)
+
+    def note_dispatch(self, batch: int, t_dispatch: float, bucket: int,
+                      rows: int, head_wait_s: float) -> None:
+        """Log one dispatch (see ``dispatch_log``)."""
+        self.dispatch_log.append((batch, t_dispatch, bucket, rows,
+                                  head_wait_s))
+
     def note_dispatch_timing(self, pack_s: float, wait_s: float,
                              overlapped_s: float) -> None:
         """Account one dispatch's host-pack time, blocked device wait,
@@ -233,9 +276,12 @@ class ServeMetrics:
     def note_decision(self, session: str, latency_s: float,
                       now: float) -> None:
         """Account one streamed keyword decision for ``session``."""
-        rec = self.session_decisions.setdefault(str(session), {
-            "n": 0, "t_first": float(now), "t_last": float(now),
-            "recent": deque(maxlen=self.SESSION_LATENCY_WINDOW)})
+        sid = str(session)
+        rec = self.session_decisions.get(sid)
+        if rec is None:
+            rec = self.session_decisions[sid] = {
+                "n": 0, "t_first": float(now), "t_last": float(now),
+                "recent": deque(maxlen=self.SESSION_LATENCY_WINDOW)}
         rec["n"] += 1
         rec["t_last"] = float(now)
         rec["recent"].append(float(latency_s))

@@ -42,10 +42,12 @@ from collections import deque
 from typing import Deque, Dict, Iterable, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.booleanize import Booleanizer, StreamingBooleanizer
 from repro.serve.batching import QOS_BULK, QueueFull, validate_qos
 from repro.serve.engine import ServeEngine
+from repro.serve.metrics import SPANS
 
 DECISION_MODES = ("argmax", "margin")
 
@@ -177,7 +179,8 @@ class StreamSession:
         """Push raw ``[T, F]`` frames; submits every window they complete
         to the shared engine under the session's QoS class.  Returns the
         submitted request ids."""
-        rows = self.windows.push(frames)
+        with TraceAnnotation(SPANS["push"]):
+            rows = self.windows.push(frames)
         rids = [self.engine.submit(row, qos=self.scfg.qos)
                 for row in rows]
         self._pending.extend(rids)
@@ -325,8 +328,9 @@ class StreamServer:
 
     def _collect(self) -> List[Decision]:
         out: List[Decision] = []
-        for s in self.sessions.values():
-            out.extend(s.collect())
+        with TraceAnnotation(SPANS["scan"]):
+            for s in self.sessions.values():
+                out.extend(s.collect())
         return out
 
     def pump(self) -> List[Decision]:

@@ -126,3 +126,45 @@ def test_kernel_compiles_for_v5e(kernel, model, one_chip, no_compile_cache):
     cfg, o = _operands(model, one_chip)
     compiled = _lower(kernel, cfg, o).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_forward_and_kernel_keep_the_names_the_trace_reads(
+        one_chip, no_compile_cache):
+    """The benchmark's trace reduction (``bench/readings.py``) finds the
+    serving step by its module name and the kernel by its op name: the
+    engine's forward, compiled for the chip at the KWS-6 Table IV width,
+    must be module ``jit_fwd`` holding a ``%imbue_class_sums*`` custom
+    call."""
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench"))
+    import readings
+    from repro.core.tm import TMConfig
+    from repro.core.variations import VariationConfig
+    from repro.serve import BatcherConfig, EngineConfig, ServeEngine
+
+    assert (readings.FORWARD_MODULE, readings.KERNEL_OP) == (
+        "jit_fwd", "%imbue_class_sums")
+    cfg = TMConfig(n_classes=6, clauses_per_class=300, n_features=377,
+                   n_states=127)
+    inc = jax.random.bernoulli(jax.random.PRNGKey(5), 0.006,
+                               (cfg.n_clauses, cfg.n_literals))
+    ta = jnp.where(inc, cfg.n_states + 1, cfg.n_states).astype(
+        cfg.state_dtype)
+    engine = ServeEngine.from_ta_state(
+        ta, cfg, vcfg=VariationConfig.nominal(),
+        ecfg=EngineConfig(interpret=False, batcher=BatcherConfig(
+            max_batch=32, bucket_sizes=(32,))))
+    assert engine.backend.name == "analog-pallas-packed2"
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    lits = jax.ShapeDtypeStruct((32, bitpack.words_for(cfg.n_literals)),
+                                jnp.uint32, sharding=one_chip)
+    text = engine._fwd.lower(jax.tree.map(spec, engine._slices[0]), lits,
+                             None, spec(engine._mask_one),
+                             bt=32).compile().as_text()
+    assert text.startswith(f"HloModule {readings.FORWARD_MODULE},")
+    assert any(line.lstrip().startswith(readings.KERNEL_OP)
+               and "custom-call" in line for line in text.splitlines())
