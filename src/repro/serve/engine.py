@@ -279,6 +279,12 @@ class InFlight:
     # (see _resident_model_nbytes); lands in ServeMetrics at collect.
     resident_nbytes: int = 0
 
+    def outputs(self) -> tuple:
+        """The device outputs the host fetches: sums, preds, and a
+        canary batch's shadow predictions."""
+        return (self.sums, self.preds) if self.shadow_preds is None \
+            else (self.sums, self.preds, self.shadow_preds)
+
 
 @dataclasses.dataclass
 class _Canary:
@@ -808,21 +814,21 @@ class ServeEngine:
         batch's device time shows up as its own blocked wait."""
         with TraceAnnotation(SPANS["collect"], batch=fl.seq):
             t_wait0 = self.clock()
-            waits = (fl.sums, fl.preds) if fl.shadow_preds is None \
-                else (fl.sums, fl.preds, fl.shadow_preds)
             with TraceAnnotation(SPANS["block"]):
-                jax.block_until_ready(waits)
+                jax.block_until_ready(fl.outputs())
             t_done = self.clock()
             blocked_elsewhere = self._blocked_s - fl.blocked_snapshot
             overlapped = max(0.0, (t_wait0 - fl.t_issue) - blocked_elsewhere)
             self._blocked_s += t_done - t_wait0
             preds = np.asarray(fl.preds)
             sums = np.asarray(fl.sums)
+            shadow = (None if fl.shadow_preds is None
+                      else np.asarray(fl.shadow_preds))
+            self.metrics.note_fetch(self.clock() - t_done)
             batch = fl.batch
-            if fl.shadow_preds is not None:       # canary batch: score the
-                shadow = np.asarray(fl.shadow_preds)  # stable pool's argmax
-                agree = int((preds[:batch.n_valid]       # on the valid rows
-                             == shadow[:batch.n_valid]).sum())
+            if shadow is not None:                # canary batch: score the
+                agree = int((preds[:batch.n_valid]  # stable pool's argmax
+                             == shadow[:batch.n_valid]).sum())  # valid rows
                 self.metrics.note_canary(batch.n_valid, agree)
 
             records = []
@@ -1152,9 +1158,10 @@ class AsyncServeEngine(ServeEngine):
     Same construction surface, routing semantics, and per-seed noise
     stream as :class:`ServeEngine` — only the dispatch schedule changes.
     ``_dispatch`` *issues* the fused jit'd call (device futures; no
-    host block) and defers collection until ``ecfg.max_in_flight``
-    newer dispatches are outstanding, a result is requested, or the
-    engine drains.  With the default depth of 2, the host packs and
+    host block), starts the copies of its outputs to the host, and
+    defers collection until ``ecfg.max_in_flight`` newer dispatches are
+    outstanding, a pump finds it finished, a result is requested, or
+    the engine drains.  With the default depth of 2, the host packs and
     issues batch N+1 while batch N's kernel is in flight — the classic
     pipeline that makes serving throughput track device time instead of
     host+device time.  Responses still come back in submission order
@@ -1175,7 +1182,21 @@ class AsyncServeEngine(ServeEngine):
     def _dispatch(self, batch: Batch) -> None:
         while len(self._pending) >= self.ecfg.max_in_flight:
             self._collect(self._pending.popleft())
-        self._pending.append(self._issue(batch))
+        fl = self._issue(batch)
+        self._prefetch(fl)
+        self._pending.append(fl)
+
+    @staticmethod
+    def _prefetch(fl: InFlight) -> None:
+        """Start the device-to-host copies of a dispatch's outputs now:
+        the runtime moves them as soon as the kernel finishes, and the
+        ``np.asarray`` in :meth:`_collect` picks up the landed copy
+        instead of waiting out the transfer."""
+        try:
+            for out in fl.outputs():
+                out.copy_to_host_async()
+        except AttributeError:      # non-jax arrays (test doubles)
+            pass
 
     def _collect_ready(self) -> None:
         # Opportunistically collect, at every pump, dispatches whose
@@ -1193,10 +1214,7 @@ class AsyncServeEngine(ServeEngine):
     @staticmethod
     def _is_ready(fl: InFlight) -> bool:
         try:
-            ready = bool(fl.preds.is_ready() and fl.sums.is_ready())
-            if ready and fl.shadow_preds is not None:
-                ready = bool(fl.shadow_preds.is_ready())
-            return ready
+            return all(out.is_ready() for out in fl.outputs())
         except AttributeError:      # non-jax arrays (test doubles)
             return True
 

@@ -128,6 +128,9 @@ class ServeMetrics:
         self.host_pack_s = 0.0
         self.device_wait_s = 0.0
         self.overlapped_s = 0.0
+        # Host time spent inside the collect's ``np.asarray`` of the
+        # outputs: the device-to-host copy still waited on.
+        self.host_fetch_s = 0.0
         # Live hot-swap accounting (ISSUE 7): which pool model
         # generation served each request, every swap/promote/rollback
         # event, and the canary comparison tallies.  ``canary_rows``
@@ -260,6 +263,10 @@ class ServeMetrics:
         """Log one dispatch (see ``dispatch_log``)."""
         self.dispatch_log.append((batch, t_dispatch, bucket, rows,
                                   head_wait_s))
+
+    def note_fetch(self, fetch_s: float) -> None:
+        """Account host time spent fetching one dispatch's outputs."""
+        self.host_fetch_s += max(0.0, fetch_s)
 
     def note_dispatch_timing(self, pack_s: float, wait_s: float,
                              overlapped_s: float) -> None:
@@ -423,6 +430,7 @@ class ServeMetrics:
                "host_pack_s": self.host_pack_s,
                "device_wait_s": self.device_wait_s,
                "overlap_fraction": self.overlap_fraction(),
+               "host_fetch_s": self.host_fetch_s,
                # Always present (zeros = the no-drop evidence chaos
                # harnesses assert on), never elided like the optional
                # blocks below.

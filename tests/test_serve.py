@@ -12,9 +12,9 @@ import pytest
 
 from repro.core import imbue, tm
 from repro.core.variations import VariationConfig
-from repro.serve import (AsyncServeEngine, BatcherConfig, DynamicBatcher,
-                         EngineConfig, ServeEngine, ensemble_vote,
-                         program_replica_pool)
+from repro.serve import (CANARY, AsyncServeEngine, BatcherConfig,
+                         DynamicBatcher, EngineConfig, ServeEngine,
+                         ensemble_vote, program_replica_pool)
 
 
 class FakeClock:
@@ -417,6 +417,159 @@ def test_async_engine_validates_depth(small_cfg, random_ta, keys):
             random_ta, small_cfg, key=keys["route"],
             vcfg=VariationConfig.nominal(),
             ecfg=EngineConfig(max_in_flight=0))
+
+
+def _noisy_engine(cls, small_cfg, random_ta, keys, canary):
+    """A D2D + C2C engine over buckets 8/16/32, optionally with half of
+    its batches served by a canary (replica 1's state as version 1)."""
+    eng = cls.from_ta_state(
+        random_ta, small_cfg, n_replicas=2, key=keys["route"],
+        vcfg=VariationConfig(csa_offset=False),
+        ecfg=EngineConfig(batcher=BatcherConfig(
+            max_batch=32, bucket_sizes=(8, 16, 32))))
+    if canary:
+        eng.arm_canary(eng._slices[1], 1, 0.5)
+    return eng
+
+
+@pytest.mark.parametrize("canary", [False, True])
+def test_async_responses_bit_identical_to_sync(small_cfg, random_ta,
+                                               boolean_batch, keys, canary,
+                                               monkeypatch):
+    """Starting the result copies at issue and collecting on a later
+    pump changes no response: an async engine hands back the sync
+    engine's predictions, class sums, replicas and versions bit for bit
+    (same read keys), across buckets and with a canary armed.  Only the
+    async engine starts the copies, for every output of every
+    dispatch."""
+    array_cls = type(jnp.zeros(1))
+    copy = array_cls.copy_to_host_async
+    started = []
+    monkeypatch.setattr(array_cls, "copy_to_host_async",
+                        lambda a: (started.append(a.shape), copy(a))[1])
+    out = {}
+    for cls in (ServeEngine, AsyncServeEngine):
+        started.clear()
+        eng = _noisy_engine(cls, small_cfg, random_ta, keys, canary)
+        rids = []
+        for lo, hi in ((0, 5), (5, 17), (17, 47), (47, 64)):  # 8/16/32/32
+            rids += eng.submit_many(list(boolean_batch[lo:hi]))
+            eng.pump(force=True)
+        rs = eng.drain()
+        assert [r.rid for r in rs] == rids
+        out[cls] = (rs, eng.summary(), list(started))
+    (sync, s_sync, c_sync), (asy, s_async, c_async) = (
+        out[ServeEngine], out[AsyncServeEngine])
+    for a, b in zip(asy, sync):
+        assert (a.pred, a.replica, a.version) == (b.pred, b.replica,
+                                                  b.version)
+        np.testing.assert_array_equal(a.class_sums, b.class_sums)
+    assert s_async["batches"] == s_sync["batches"] == 4
+    assert c_sync == []
+    shadows = s_async["canary"]["batches"] if canary else 0
+    assert len(c_async) == 2 * s_async["batches"] + shadows
+    assert s_async["host_fetch_s"] >= 0 and s_sync["host_fetch_s"] > 0
+    assert ("canary" in s_async) == canary
+    if canary:
+        assert s_async["canary"] == s_sync["canary"]
+        assert {r.replica for r in asy} >= {CANARY}
+
+
+def test_async_engine_serves_non_jax_results(small_cfg, random_ta,
+                                             boolean_batch, keys):
+    """A forward that hands back plain numpy arrays (no async copy, no
+    readiness) is still served, in order and unchanged."""
+    eng = AsyncServeEngine.from_ta_state(
+        random_ta, small_cfg, n_replicas=2, key=keys["route"],
+        vcfg=VariationConfig.nominal(),
+        ecfg=EngineConfig(batcher=BatcherConfig(max_batch=16,
+                                                bucket_sizes=(8, 16))))
+    fwd = eng._fwd
+    eng._fwd = lambda *a, **kw: tuple(np.asarray(o) for o in fwd(*a, **kw))
+    rids = eng.submit_many(list(boolean_batch))
+    rs = eng.drain()
+    assert [r.rid for r in rs] == rids
+    digital = np.asarray(tm.predict(random_ta, jnp.asarray(boolean_batch),
+                                    small_cfg))
+    np.testing.assert_array_equal(np.array([r.pred for r in rs]), digital)
+    s = eng.summary()
+    assert s["batches"] == 4 and s["requests"] == len(rids)
+
+
+def _bucket8_engine(random_ta, small_cfg, keys, clock):
+    """A nominal async engine on ``clock`` whose 8-row bucket cuts as
+    soon as 8 requests are queued (2 ms bulk deadline)."""
+    return AsyncServeEngine.from_ta_state(
+        random_ta, small_cfg, n_replicas=2, key=keys["route"],
+        vcfg=VariationConfig.nominal(), clock=clock,
+        ecfg=EngineConfig(batcher=BatcherConfig(max_batch=8,
+                                                bucket_sizes=(8,))))
+
+
+@pytest.mark.parametrize("then", ["pump", "result", "drain"])
+def test_async_collects_a_dispatch_on_a_later_pump(small_cfg, random_ta,
+                                                   boolean_batch, keys,
+                                                   then, monkeypatch):
+    """A dispatch whose device work is still running at the end of the
+    pump that issued it stays in flight through that pump (``take``
+    finds nothing), with its result copies already started.  The next
+    ``pump()`` that finds it done collects it, and ``result()`` or
+    ``drain()`` collect it at once."""
+    array_cls = type(jnp.zeros(1))
+    copy = array_cls.copy_to_host_async
+    started = []
+    monkeypatch.setattr(array_cls, "copy_to_host_async",
+                        lambda a: (started.append(a.shape), copy(a))[1])
+    eng = _bucket8_engine(random_ta, small_cfg, keys, FakeClock())
+    running = [True]                    # the device, as the pump sees it
+    ready = AsyncServeEngine._is_ready
+    eng._is_ready = lambda fl: not running[0] and ready(fl)
+    rids = eng.submit_many(list(boolean_batch[:8]))
+    eng.pump()
+    assert eng.in_flight == 1
+    assert eng.take(rids[0]) is None
+    assert sorted(started) == [(8,), (8, small_cfg.n_classes)]
+    running[0] = False
+    jax.block_until_ready(eng._pending[0].outputs())
+    if then == "pump":
+        eng.pump()
+        got = [eng.take(r) for r in rids]
+    elif then == "result":
+        got = [eng.result(r) for r in rids]
+    else:
+        got = eng.drain()
+    assert eng.in_flight == 0
+    assert [r.rid for r in got] == rids
+    digital = np.asarray(tm.predict(
+        random_ta, jnp.asarray(boolean_batch[:8]), small_cfg))
+    np.testing.assert_array_equal(np.array([r.pred for r in got]), digital)
+
+
+@pytest.mark.parametrize("gap_s", [None, 1e-3, 10e-3])
+def test_async_returns_a_finished_dispatch_from_its_own_pump(
+        small_cfg, random_ta, boolean_batch, keys, gap_s):
+    """A dispatch whose device work is done by the end of the pump that
+    issued it comes back from that pump, whether pumps come often
+    (1 ms apart), seldom (10 ms, past the 2 ms deadline, as a caller
+    that pumps once per arrival at a low rate does) or for the first
+    time: nothing waits for a pump that may come only with the next
+    arrival."""
+    clock = FakeClock()
+    eng = _bucket8_engine(random_ta, small_cfg, keys, clock)
+    ready = AsyncServeEngine._is_ready
+    eng._is_ready = lambda fl: (jax.block_until_ready(fl.outputs())
+                                and ready(fl))
+    if gap_s is not None:
+        eng.pump()
+        clock.advance(gap_s)
+    rids = eng.submit_many(list(boolean_batch[:8]))
+    eng.pump()
+    assert eng.in_flight == 0
+    got = [eng.take(r) for r in rids]
+    assert [r.rid for r in got] == rids
+    digital = np.asarray(tm.predict(
+        random_ta, jnp.asarray(boolean_batch[:8]), small_cfg))
+    np.testing.assert_array_equal(np.array([r.pred for r in got]), digital)
 
 
 def test_metrics_accounting(small_cfg, random_ta, keys):

@@ -15,7 +15,9 @@ the main pytest process keeps its single device) — the same pattern as
 * the 1-fused-dispatch property holds under a sharded mesh (trace-count
   check mirroring the single-device 1-kernel-call stack test);
 * full-noise sharded serving is bit-reproducible and equal to the
-  single-device noise stream (partitionable threefry).
+  single-device noise stream (partitionable threefry);
+* the async engine's result copies, started at issue on sharded
+  outputs, change no response.
 """
 
 import os
@@ -241,6 +243,36 @@ def test_async_overlap_metrics_on_mesh():
         print("OK async mesh", max(seen))
     """)
     assert "OK async mesh" in out
+
+
+def test_async_prefetch_on_mesh_matches_sync():
+    """Result copies started at issue on sharded outputs (every shard
+    copies): an async mesh engine starts both copies of every dispatch
+    and serves the sync mesh engine's responses bit for bit, routed and
+    ensemble; the sync engine starts none."""
+    out = run_devices("""
+        array_cls = type(jnp.zeros(1))
+        copy = array_cls.copy_to_host_async
+        started = []
+        array_cls.copy_to_host_async = (
+            lambda a: (started.append(len(a.sharding.device_set)),
+                       copy(a))[1])
+        mesh = make_replica_mesh(8, 1)
+        for routing in ("round_robin", "ensemble"):
+            got = {}
+            for cls in (ServeEngine, AsyncServeEngine):
+                started.clear()
+                eng = engine(8, mesh=mesh, cls=cls, routing=routing)
+                got[cls] = served(eng) + (eng.summary(), list(started))
+            (p0, s0, m0, c0), (p1, s1, m1, c1) = (got[ServeEngine],
+                                                  got[AsyncServeEngine])
+            np.testing.assert_array_equal(p1, p0)
+            np.testing.assert_array_equal(s1, s0)
+            assert m1["batches"] == m0["batches"] == 3, (m1, m0)
+            assert c0 == [] and len(c1) == 2 * m1["batches"], (c0, c1)
+        print("OK prefetch mesh", sorted(set(c1)))
+    """)
+    assert "OK prefetch mesh" in out
 
 
 def test_coalesced_sharded_engine_class_parallel():
