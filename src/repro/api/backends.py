@@ -74,6 +74,7 @@ from repro.core import coalesced as co
 from repro.core import imbue
 from repro.core import tm
 from repro.kernels import ops
+from repro.kernels.imbue_infer import DOTS_DEFAULT
 
 
 def _to_i32(sums: jax.Array) -> jax.Array:
@@ -137,7 +138,8 @@ def digital_pallas_packed(state: DigitalState, lits: jax.Array,
                   capabilities={CAP_ANALOG, CAP_MODELS_C2C,
                                 CAP_MODELS_CSA_OFFSET, CAP_REPLICA_VMAP,
                                 CAP_SHARDED},
-                  priority=10)
+                  priority=10,
+                  dot_mode=lambda s, keyed: imbue.DOT_PRECISION.name.lower())
 def analog_jnp(state, lits: jax.Array,
                key: Optional[jax.Array] = None) -> jax.Array:
     """Einsum KCL + per-column CSA compare (full noise model).
@@ -164,7 +166,7 @@ def analog_jnp(state, lits: jax.Array,
                   state_types=(CrossbarState, ReplicaStackState),
                   capabilities={CAP_ANALOG, CAP_FUSED_KERNEL,
                                 CAP_MODELS_C2C, CAP_REPLICA_VMAP},
-                  priority=20)
+                  priority=20, dot_mode=lambda s, keyed: DOTS_DEFAULT)
 def analog_pallas(state, lits: jax.Array,
                   key: Optional[jax.Array] = None, **tiles) -> jax.Array:
     """Fused Boolean-to-Current Pallas kernel (scalar v_ref threshold).
@@ -189,7 +191,8 @@ def analog_pallas(state, lits: jax.Array,
                   capabilities={CAP_ANALOG, CAP_FUSED_KERNEL,
                                 CAP_MODELS_C2C, CAP_REPLICA_VMAP,
                                 CAP_PACKED_IO},
-                  priority=30, predicate=lambda s: s.packed)
+                  priority=30, predicate=lambda s: s.packed,
+                  dot_mode=lambda s, keyed: DOTS_DEFAULT)
 def analog_pallas_packed(state, lits: jax.Array,
                          key: Optional[jax.Array] = None,
                          **tiles) -> jax.Array:
@@ -214,7 +217,9 @@ def analog_pallas_packed(state, lits: jax.Array,
                   capabilities={CAP_ANALOG, CAP_FUSED_KERNEL,
                                 CAP_MODELS_C2C, CAP_REPLICA_VMAP,
                                 CAP_PACKED_IO, CAP_PACKED_PLANES},
-                  priority=40, predicate=lambda s: s.plane_packed)
+                  priority=40, predicate=lambda s: s.plane_packed,
+                  dot_mode=lambda s, keyed: ops.planes_dot_mode(
+                      s.plane_dev, s.vcfg, keyed=keyed))
 def analog_pallas_packed2(state, lits: jax.Array,
                           key: Optional[jax.Array] = None,
                           **tiles) -> jax.Array:

@@ -65,6 +65,11 @@ class Backend:
     # backends require the state to carry a packed include plane
     # (``state.packed``).  None means "type match is enough".
     predicate: Optional[Callable] = None
+    # How the forward's crossbar column dots run, as the backend itself
+    # chooses them: ``dot_mode(state, keyed)`` -> a mode name (``keyed``:
+    # the reads carry a noise key).  None on paths without crossbar
+    # currents.
+    dot_mode: Optional[Callable] = None
 
     def accepts(self, state) -> bool:
         if not isinstance(state, self.state_types):
@@ -93,7 +98,8 @@ _REGISTRY: Dict[str, Backend] = {}
 
 
 def register_backend(name: str, *, state_types, capabilities,
-                     priority: int = 0, doc: str = "", predicate=None):
+                     priority: int = 0, doc: str = "", predicate=None,
+                     dot_mode=None):
     """Decorator: register ``fn`` as backend ``name``."""
     unknown = frozenset(capabilities) - KNOWN_CAPABILITIES
     if unknown:
@@ -107,7 +113,8 @@ def register_backend(name: str, *, state_types, capabilities,
             name=name, fn=fn, state_types=tuple(state_types),
             capabilities=frozenset(capabilities), priority=priority,
             doc=doc or (fn.__doc__ or "").strip().splitlines()[0]
-            if (doc or fn.__doc__) else "", predicate=predicate)
+            if (doc or fn.__doc__) else "", predicate=predicate,
+            dot_mode=dot_mode)
         return fn
 
     return deco

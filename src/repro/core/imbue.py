@@ -38,6 +38,8 @@ from repro.core.tm import TMConfig, class_sums, include_mask, literals
 # Nominal single-cell read currents (Table I).
 I_INCLUDE_ON = var.V_READ / (var.SERIES_FACTOR * var.LRS_MEAN_OHM)   # ~75.7 uA
 I_EXCLUDE_ON = var.V_READ / (var.SERIES_FACTOR * var.HRS_MEAN_OHM)   # ~1.89 uA
+# Precision of the column-current einsums (``column_currents_raw``).
+DOT_PRECISION = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,8 +135,14 @@ def column_currents_raw(
     lit1 = pad_to_columns(lits.astype(jnp.float32), mapping)  # [B, K, W]
     g_on_f = pad_to_columns(g_on, mapping)                    # [C, K, W]
     i_leak_f = pad_to_columns(i_leak, mapping)
-    on = jnp.einsum("bkw,ckw->bck", lit0, g_on_f)
-    leak = jnp.einsum("bkw,ckw->bck", lit1, i_leak_f)
+    # f32 products with f32 accumulation on every device.  At a TPU's
+    # default precision both operands are rounded to bfloat16 (8
+    # significant bits: up to 0.2% each, 0.1% for the 0.2 V drive),
+    # which flips clauses whose column current lies that close to the
+    # reference, as D2D variation puts some.
+    on = jnp.einsum("bkw,ckw->bck", lit0, g_on_f, precision=DOT_PRECISION)
+    leak = jnp.einsum("bkw,ckw->bck", lit1, i_leak_f,
+                      precision=DOT_PRECISION)
     return on + leak
 
 

@@ -107,6 +107,17 @@ def imbue_infer_packed_kernel(scal_ref, litw_ref, g_t_ref, leak_t_ref,
                                 preferred_element_type=jnp.float32)
 
 
+# How a kernel's column dots run, as ``dot_mode`` reports it.
+DOTS_DEFAULT = "default"        # f32 operands at the compiler's default
+DOTS_BF16X3 = "bf16x3"          # 0/1 literals x 3 bf16 terms, f32 sums
+
+
+def dot_mode(has_dev: bool) -> str:
+    """The column-dot mode of the plane-packed kernel for a chip with
+    (``has_dev``) or without a deviation plane."""
+    return DOTS_BF16X3 if has_dev else DOTS_DEFAULT
+
+
 def imbue_infer_planes_kernel(*refs, width, cols_per_block, nk, has_dev):
     """Plane-packed variant: the conductance stack never reaches the
     kernel as f32.  It arrives as (a) the LRS/HRS include-index bitplane
@@ -131,6 +142,12 @@ def imbue_infer_planes_kernel(*refs, width, cols_per_block, nk, has_dev):
     kernels.  Word-padded columns past ``l_valid`` would otherwise
     reconstruct as HRS cells (the f32 path zero-pads them away), so an
     in-kernel validity mask zeroes their ``g``/``leak`` contributions.
+
+    The column dots follow ``has_dev`` (:func:`dot_mode`): a deviating
+    chip's currents are summed from f32 products (``exact_columns``),
+    since D2D draws put some columns within a bfloat16 rounding of the
+    reference; a nominal chip keeps the one-pass dots of the f32-plane
+    kernels, whose currents sit 11% from the reference on either side.
 
     Grid is (replica, B block, C block): replica ``r`` reads its own
     ``[L, C]`` slab of the ``[R, L, C]`` deviation stack, so a whole
@@ -161,6 +178,31 @@ def imbue_infer_planes_kernel(*refs, width, cols_per_block, nk, has_dev):
 
     and_ref[...] = jnp.ones_like(and_ref)
 
+    def exact_columns(bits, on, leak):
+        """Column currents in f32 for a deviating chip.  A cell carries
+        ``on`` when its literal is 0 and ``leak`` when it is 1, so a
+        column reads ``sum(leak) + lit0 . (on - leak)``.  ``lit0`` is 0
+        or 1, exact in bf16, and ``on - leak`` is split into three bf16
+        terms (8 + 8 + 8 bits of its f32 significand), so three
+        single-pass dots give the f32 products, accumulated in f32."""
+        d = on - leak
+        d_hi = d.astype(jnp.bfloat16)
+        rest = d - d_hi.astype(jnp.float32)
+        d_mid = rest.astype(jnp.bfloat16)
+        d_lo = (rest - d_mid.astype(jnp.float32)).astype(jnp.bfloat16)
+        lit0 = (1.0 - bits).astype(jnp.bfloat16)
+        for w in range(cols_per_block):
+            lo, hi = w * width, (w + 1) * width
+            x = lit0[:, lo:hi]
+            i_col = (jnp.sum(leak[lo:hi, :], axis=0, keepdims=True)
+                     + (jnp.dot(x, d_hi[lo:hi, :],
+                                preferred_element_type=jnp.float32)
+                        + jnp.dot(x, d_mid[lo:hi, :],
+                                  preferred_element_type=jnp.float32)
+                        + jnp.dot(x, d_lo[lo:hi, :],
+                                  preferred_element_type=jnp.float32)))
+            and_ref[...] *= (i_col < i_ref).astype(jnp.float32)
+
     def compute_chunk(k, inc_words, dev_tile):
         bits_inc = unpack_words_f32_cols(inc_words, n_bits=kt)  # [kt, ct]
         r_nom = bits_inc * r_lrs + (1.0 - bits_inc) * r_hrs
@@ -174,6 +216,12 @@ def imbue_infer_planes_kernel(*refs, width, cols_per_block, nk, has_dev):
         leak = jnp.where(valid, leak_nom * (r_nom / r), 0.0)
 
         bits = unpack_words_f32(litw_ref[k], n_bits=kt)         # [bt, kt]
+        if dev_tile is not None:
+            exact_columns(bits, v_read * g, leak)
+            return
+        # Nominal chip: every column current is a class-nominal value,
+        # 11% from the reference, so the one-pass dots of the f32-plane
+        # kernels (operands rounded by at most 0.2%) sense exactly.
         v_drive = (1.0 - bits) * v_read
         for w in range(cols_per_block):
             lo, hi = w * width, (w + 1) * width

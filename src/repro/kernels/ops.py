@@ -447,6 +447,19 @@ def _read_dev(plane_index: jax.Array, plane_dev: jax.Array | None,
     return apply_c2c(key, r, include, vcfg) - r_nom
 
 
+def _senses_dev(plane_dev, vcfg, keyed: bool) -> bool:
+    """Whether a plane-packed read senses a deviation plane: the
+    programmed one, or a fresh C2C draw on a keyed read."""
+    return plane_dev is not None or (keyed and vcfg.c2c)
+
+
+def planes_dot_mode(plane_dev, vcfg, *, keyed: bool) -> str:
+    """The column-dot mode (``imbue_infer.dot_mode``) the plane-packed
+    kernel runs for a read of this chip, from the predicate the read
+    dispatches on."""
+    return _ai.dot_mode(_senses_dev(plane_dev, vcfg, keyed))
+
+
 def _planes_sums(litw, plane_index, devs, icfg, cfg, *, l_valid, bt, ct, kt,
                  interpret):
     """One plane-packed kernel call: ``devs`` ``[R, C, L]`` (or None for
@@ -544,6 +557,9 @@ def imbue_class_sums_stack_planes(
     from repro.core.variations import VariationConfig
     vcfg = vcfg or VariationConfig.nominal()
     opts = dict(l_valid=l_valid, bt=bt, ct=ct, kt=kt, interpret=interpret)
+    if not _senses_dev(plane_dev, vcfg, key is not None):
+        out = _planes_sums(litw, plane_index, None, icfg, cfg, **opts)
+        return jnp.broadcast_to(out, (n_replicas,) + out.shape[1:])
     if key is not None and vcfg.c2c:
         keys = jax.random.split(key, n_replicas)
         if plane_dev is None:
@@ -552,9 +568,6 @@ def imbue_class_sums_stack_planes(
         else:
             devs = jax.vmap(lambda d, k: _read_dev(
                 plane_index, d, k, vcfg, l_valid))(plane_dev, keys)
-    elif plane_dev is None:
-        out = _planes_sums(litw, plane_index, None, icfg, cfg, **opts)
-        return jnp.broadcast_to(out, (n_replicas,) + out.shape[1:])
     else:
         devs = plane_dev
     return _planes_sums(litw, plane_index, devs, icfg, cfg, **opts)

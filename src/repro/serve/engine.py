@@ -965,11 +965,15 @@ class ServeEngine:
         """Per-dispatch resident operand bytes for the full state
         (ensemble dispatch) and one replica slice (routed dispatch) —
         recomputed whenever the pool changes, since fault injection can
-        grow a nominal plane-packed pool a deviation plane."""
+        grow a nominal plane-packed pool a deviation plane (which also
+        changes the kernel's dot mode)."""
         self._resident_full = _resident_model_nbytes(self.state,
                                                      self.backend)
         self._resident_slice = _resident_model_nbytes(self._slices[0],
                                                       self.backend)
+        dots = self.backend.dot_mode
+        self.metrics.crossbar_dots = (
+            None if dots is None else dots(self.state, not self._noise_free))
 
     def arm_canary(self, state, version: int, fraction: float) -> None:
         """Mount a candidate single-chip state beside the stable pool.

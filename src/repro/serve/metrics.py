@@ -111,6 +111,9 @@ class ServeMetrics:
         # (+ an optional f32 deviation plane), so this is where the
         # packed-plane win shows up in serve_bench.
         self.resident_bytes = 0
+        # How the forward's crossbar column dots run (set by the engine
+        # with its state and backend; None off the analog paths).
+        self.crossbar_dots: Optional[str] = None
         self.t_first: Optional[float] = None
         self.t_last: Optional[float] = None
         # Capability-selection fallbacks (distinct reasons + count of
@@ -425,6 +428,7 @@ class ServeMetrics:
                "resident_bytes_per_dispatch": (
                    self.resident_bytes / self.batches
                    if self.batches else 0.0),
+               "crossbar_dots": self.crossbar_dots,
                "forward_fallbacks": list(self.forward_fallbacks),
                "fallback_dispatches": self.fallback_dispatches,
                "host_pack_s": self.host_pack_s,

@@ -233,6 +233,37 @@ def test_selection_prefers_planes_backend_for_plane_packed_state(states):
         states["stack_planes"].include_packed
 
 
+@pytest.mark.parametrize("chip,keyed,mode", [
+    ("nominal", False, "default"),
+    ("nominal", True, "default"),
+    ("c2c", False, "default"),
+    ("c2c", True, "bf16x3"),
+    ("d2d", False, "bf16x3"),
+])
+def test_planes_dot_mode_follows_the_deviation_the_read_senses(
+        states, keys, chip, keyed, mode):
+    """The plane-packed backend reports the dot mode its kernel runs: f32
+    products (``bf16x3``) whenever the read senses a deviation plane, a
+    programmed D2D one or a keyed C2C draw; the default dots otherwise.
+    The other analog backends report theirs; digital paths have none."""
+    st = states["stack_planes"]
+    if chip == "c2c":
+        st = dataclasses.replace(st, vcfg=dataclasses.replace(NOMINAL,
+                                                              c2c=True))
+    elif chip == "d2d":
+        inc = st.include
+        st = api.ReplicaStackState.program(
+            inc, keys["program"], 2, st.tm_cfg,
+            VariationConfig(c2c=False, csa_offset=False)).pack_planes()
+        assert st.plane_dev is not None
+    assert api.get_backend("analog-pallas-packed2").dot_mode(
+        st, keyed) == mode
+    assert api.get_backend("analog-jnp").dot_mode(st, keyed) == "highest"
+    assert api.get_backend("analog-pallas").dot_mode(st, keyed) == "default"
+    assert all(b.dot_mode is None for b in api.list_backends()
+               if api.CAP_ANALOG not in b.capabilities)
+
+
 def test_selection_packed_state_with_csa_noise_falls_back(small_cfg, keys):
     """csa_offset still wins over packed preference: the packed kernel
     lacks models_csa_offset, so a noisy read falls back (loudly) to

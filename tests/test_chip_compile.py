@@ -128,13 +128,26 @@ def test_kernel_compiles_for_v5e(kernel, model, one_chip, no_compile_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# The engines whose forward the benchmark's trace reduction reads: the
+# KWS-6 Table IV width, nominal, one replica; the F-MNIST Table IV width
+# under D2D variation, an R=4 ensemble.
+TRACED_ENGINES = {
+    "kws6-nominal-r1": dict(shape=(6, 300, 377), density=0.006,
+                            replicas=1, d2d=False, routing="round_robin",
+                            bucket=32, dots="default"),
+    "fmnist-d2d-r4-ensemble": dict(shape=(10, 500, 784), density=0.0033,
+                                   replicas=4, d2d=True, routing="ensemble",
+                                   bucket=128, dots="bf16x3"),
+}
+
+
+@pytest.mark.parametrize("engine_kind", sorted(TRACED_ENGINES))
 def test_forward_and_kernel_keep_the_names_the_trace_reads(
-        one_chip, no_compile_cache):
+        engine_kind, one_chip, no_compile_cache):
     """The benchmark's trace reduction (``bench/readings.py``) finds the
-    serving step by its module name and the kernel by its op name: the
-    engine's forward, compiled for the chip at the KWS-6 Table IV width,
-    must be module ``jit_fwd`` holding a ``%imbue_class_sums*`` custom
-    call."""
+    serving step by its module name and the kernel by its op name: each
+    engine's forward, compiled for the chip, must be module ``jit_fwd``
+    holding a ``%imbue_class_sums*`` custom call."""
     import sys
     sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "bench"))
@@ -143,28 +156,61 @@ def test_forward_and_kernel_keep_the_names_the_trace_reads(
     from repro.core.variations import VariationConfig
     from repro.serve import BatcherConfig, EngineConfig, ServeEngine
 
+    e = TRACED_ENGINES[engine_kind]
     assert (readings.FORWARD_MODULE, readings.KERNEL_OP) == (
         "jit_fwd", "%imbue_class_sums")
-    cfg = TMConfig(n_classes=6, clauses_per_class=300, n_features=377,
-                   n_states=127)
-    inc = jax.random.bernoulli(jax.random.PRNGKey(5), 0.006,
+    classes, per_class, features = e["shape"]
+    cfg = TMConfig(n_classes=classes, clauses_per_class=per_class,
+                   n_features=features, n_states=127)
+    inc = jax.random.bernoulli(jax.random.PRNGKey(5), e["density"],
                                (cfg.n_clauses, cfg.n_literals))
     ta = jnp.where(inc, cfg.n_states + 1, cfg.n_states).astype(
         cfg.state_dtype)
+    vcfg = (VariationConfig(c2c=False, csa_offset=False) if e["d2d"]
+            else VariationConfig.nominal())
+    b = e["bucket"]
     engine = ServeEngine.from_ta_state(
-        ta, cfg, vcfg=VariationConfig.nominal(),
-        ecfg=EngineConfig(interpret=False, batcher=BatcherConfig(
-            max_batch=32, bucket_sizes=(32,))))
+        ta, cfg, n_replicas=e["replicas"], vcfg=vcfg,
+        ecfg=EngineConfig(interpret=False, routing=e["routing"],
+                          batcher=BatcherConfig(max_batch=b,
+                                                bucket_sizes=(b,))))
     assert engine.backend.name == "analog-pallas-packed2"
+    assert not engine.selection.fell_back
+    assert engine.summary()["crossbar_dots"] == e["dots"]
+    ensemble = e["routing"] == "ensemble"
+    state = engine.state if ensemble else engine._slices[0]
+    mask = engine._healthy_mask if ensemble else engine._mask_one
 
     def spec(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
 
-    lits = jax.ShapeDtypeStruct((32, bitpack.words_for(cfg.n_literals)),
+    lits = jax.ShapeDtypeStruct((b, bitpack.words_for(cfg.n_literals)),
                                 jnp.uint32, sharding=one_chip)
-    text = engine._fwd.lower(jax.tree.map(spec, engine._slices[0]), lits,
-                             None, spec(engine._mask_one),
-                             bt=32).compile().as_text()
+    text = engine._fwd.lower(jax.tree.map(spec, state), lits, None,
+                             spec(mask), bt=b).compile().as_text()
     assert text.startswith(f"HloModule {readings.FORWARD_MODULE},")
     assert any(line.lstrip().startswith(readings.KERNEL_OP)
                and "custom-call" in line for line in text.splitlines())
+
+
+def test_jnp_crossbar_dots_compile_at_highest_precision(
+        one_chip, no_compile_cache):
+    """The ``analog-jnp`` path (a sharded pool, a CSA-offset pool) reads
+    column currents through ``core.imbue``'s einsums; compiled for the
+    chip at the F-MNIST width, every contraction keeps f32 operands at
+    the highest precision rather than the TPU's one-pass bfloat16."""
+    import re
+    from repro import api
+    from repro.core.variations import VariationConfig
+    cfg, o = _operands("imbue-tm-fmnist", one_chip)
+    state = api.ReplicaStackState(
+        r_stack=o["r_stack"], include=o["include"], tm_cfg=cfg,
+        vcfg=VariationConfig(c2c=False, csa_offset=False))
+    backend = api.get_backend("analog-jnp")
+    text = jax.jit(backend.fn).lower(state, o["lits"]).compile().as_text()
+    contractions = [line for line in text.splitlines()
+                    if re.search(r"= f32\[[^]]*\]\{[^}]*\} "
+                                 r"(dot|convolution)\(", line)]
+    assert contractions
+    assert all("operand_precision={highest,highest}" in line
+               for line in contractions), contractions
