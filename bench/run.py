@@ -136,15 +136,23 @@ def run_cell(bm, cell, seed, seconds, traced, devices, *, base=loader.HERE,
                  span)
     in_window = len(compiles) - n_compiles
     if traced:
+        t_stop = time.monotonic()
         jax.profiler.stop_trace()
+        t_stop = time.monotonic() - t_stop
     peak_bytes = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                      for d in devices)
 
     summary = None
     if traced:
+        t_load = time.monotonic()
         devs, spans, window = tracekit.load(trace_dir)
+        t_reduce = time.monotonic()
         summary = tracekit.reduce(devs, spans, window)
+        t_reduce, t_load = time.monotonic() - t_reduce, t_reduce - t_load
         shutil.rmtree(trace_dir, ignore_errors=True)
+        n_gaps, n_spans = tracekit.counts(devs, spans, window)
+        print(f"trace stop {t_stop} s load {t_load} s reduce {t_reduce} s: "
+              f"{n_gaps} gaps x {n_spans} spans")
     t_end = rec.t0 + rec.seconds
     run = types.SimpleNamespace(
         rec=rec, setup_s=setup_s, trace=summary, chips=chips,

@@ -2,9 +2,11 @@
 count, the generators, the loader, the references, the controls and
 faults of the comparison, and the refusal off a TPU."""
 
+import collections
 import json
 import os
 import shutil
+import signal
 import subprocess
 import sys
 import types
@@ -64,6 +66,109 @@ def test_attribute_splits_gaps_by_host_span():
                                  tracekit.NO_SPAN: 1.25})
 
 
+def attribute_reference(idle, spans):
+    """The plain double loop: every idle interval walks the spans from
+    the first by start until one starts at or after its end."""
+    table = collections.defaultdict(float)
+    spans = sorted(spans, key=lambda s: s[1])
+    for a, b in idle:
+        covered = []
+        for name, s0, s1 in spans:
+            if s0 >= b:
+                break
+            lo, hi = max(a, s0), min(b, s1)
+            if hi > lo:
+                table[name] += hi - lo
+                covered.append((lo, hi))
+        rest = (b - a) - sum(h - l for l, h in tracekit.merge(covered))
+        if rest > 0:
+            table[tracekit.NO_SPAN] += rest
+    return dict(table)
+
+
+def _idle(rng, n, lo=0.0, hi=10.0):
+    """The gaps between ``n`` seeded busy intervals within ``[lo, hi]``."""
+    starts = rng.uniform(lo, hi, n)
+    busy = zip(starts, starts + rng.exponential(0.02, n))
+    return tracekit.gaps(tracekit.merge(busy), lo, hi)
+
+
+def _short_spans(rng, n, names, lo=0.0, hi=10.0, mean=0.01):
+    starts = rng.uniform(lo, hi, n)
+    return [(str(rng.choice(names)), float(a), float(a + d))
+            for a, d in zip(starts, rng.exponential(mean, n))]
+
+
+def _trace_case(case, rng):
+    """Idle intervals and host spans of one seeded synthetic trace."""
+    idle = _idle(rng, 400)
+    names = ["bench.intake", "bench.pump", "bench.take"]
+    if case == "nested":
+        outer = _short_spans(rng, 150, ["bench.pump"], mean=0.05)
+        inner = [("bench.take", a + (b - a) * u, a + (b - a) * (u + v))
+                 for (_, a, b), u, v in zip(outer, rng.uniform(0, 0.5, 150),
+                                            rng.uniform(0, 0.5, 150))]
+        return idle, outer + inner
+    if case == "overlapping":
+        return idle, _short_spans(rng, 600, names, mean=0.04)
+    if case == "wide":
+        return idle, _short_spans(rng, 300, names) + [
+            ("bench.wide", 2.0, 6.5), ("bench.wide", 6.0, 8.0)]
+    if case == "whole_window":
+        return idle, _short_spans(rng, 300, names) + [
+            ("bench.loop", -1.0, 11.0)]
+    if case == "zero_length":
+        spans = _short_spans(rng, 300, names)
+        spans += [("bench.tick", a, a) for _, a, _ in spans[:50]]
+        spans += [("bench.tick", a, a) for a, _ in idle[:20]]
+        return idle + [(b, b) for _, b in idle[:30]], spans
+    if case == "untouched":
+        return idle, _short_spans(rng, 100, names, lo=0.0, hi=4.0)
+    if case == "after_last_gap":
+        idle = _idle(rng, 400, 0.0, 6.0)[:-1]
+        return idle, _short_spans(rng, 300, names, 0.0, 10.0)
+    raise ValueError(case)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2 ** 31 + 7])
+@pytest.mark.parametrize("case", ["nested", "overlapping", "wide",
+                                  "whole_window", "zero_length", "untouched",
+                                  "after_last_gap"])
+def test_attribute_equals_the_double_loop(case, seed):
+    idle, spans = _trace_case(case, np.random.default_rng(seed))
+    want = attribute_reference(sorted(idle), spans)
+    got = tracekit.attribute(idle, spans)
+    assert set(got) == set(want)
+    for name, t in want.items():
+        assert got[name] == pytest.approx(t, rel=1e-12, abs=1e-12), name
+
+
+def test_attribute_stays_linear_with_a_whole_window_span():
+    rng = np.random.default_rng(2 ** 31 + 11)
+    starts = np.sort(rng.uniform(0.0, 20.0, 100_000))
+    idle = tracekit.gaps(tracekit.merge(zip(starts.tolist(),
+                                            (starts + 1e-6).tolist())),
+                         0.0, 20.0)
+    spans = _short_spans(rng, 50_000, ["bench.pump", "bench.intake"],
+                         0.0, 20.0, mean=1e-4)
+    spans.append(("bench.loop", 0.0, 20.0))
+    assert len(idle) > 99_000
+
+    def too_slow(*_):
+        raise TimeoutError("attribute took over 10 s: quadratic again?")
+
+    # A quadratic sweep would run for hours here: stop it at the limit.
+    handler = signal.signal(signal.SIGALRM, too_slow)
+    signal.setitimer(signal.ITIMER_REAL, 10.0)
+    try:
+        got = tracekit.attribute(idle, spans)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, handler)
+    assert tracekit.NO_SPAN not in got
+    assert got["bench.loop"] == pytest.approx(sum(b - a for a, b in idle))
+
+
 def test_reduce_synthetic_trace():
     dev = tracekit.DeviceTrace(
         ops=[("kernel", 1.0, 2.0), ("pad", 1.5, 2.5), ("kernel", 4.0, 5.0),
@@ -80,6 +185,7 @@ def test_reduce_synthetic_trace():
     assert s.gap_s == pytest.approx({"bench.intake": 1.5, "bench.pump": 1.0,
                                      tracekit.NO_SPAN: 4.0})
     assert s.top(s.op_s, 1) == [["kernel", 3.0]]
+    assert tracekit.counts([dev, dev], spans, (0.0, 10.0)) == (6, 2)
 
 
 def _reading_run(op_s, module_s, dispatches, chips=1):

@@ -86,14 +86,26 @@ def gaps(busy: Sequence[Interval], lo: float, hi: float) -> List[Interval]:
 
 def attribute(idle: Sequence[Interval], spans: Sequence[Named]
               ) -> Dict[str, float]:
-    """Split each idle interval among the host spans overlapping it."""
+    """Split each idle interval among the host spans overlapping it.
+
+    One sweep over the intervals and the spans, both by start: a span
+    joins the active list when it starts before an interval ends and
+    leaves it once an interval starts at or after its end, so the cost
+    is intervals plus spans plus the overlaps, however long a span is.
+    The active list keeps the spans' start order, so each interval's
+    overlaps are added in that order, as by a walk over every span.
+    """
     table: Dict[str, float] = collections.defaultdict(float)
     spans = sorted(spans, key=lambda s: s[1])
-    for a, b in idle:
+    active: List[Named] = []
+    i = 0
+    for a, b in sorted(idle):
+        while i < len(spans) and spans[i][1] < b:
+            active.append(spans[i])
+            i += 1
+        active = [s for s in active if s[2] > a]
         covered = []
-        for name, s0, s1 in spans:
-            if s0 >= b:
-                break
+        for name, s0, s1 in active:
             lo, hi = max(a, s0), min(b, s1)
             if hi > lo:
                 table[name] += hi - lo
@@ -115,8 +127,7 @@ def reduce(devices: Sequence[DeviceTrace], host_spans: Sequence[Named],
     gap_s: Dict[str, float] = collections.defaultdict(float)
     spans = [s for s in host_spans if s[0] != WINDOW_SPAN]
     for dev in devices:
-        timed = dev.ops or dev.modules
-        busy = merge(clip(((a, b) for _, a, b in timed), lo, hi))
+        busy = busy_intervals(dev, lo, hi)
         busy_total += sum(b - a for a, b in busy)
         for name, a, b in clip3(dev.modules, lo, hi):
             module_s[name] += (b - a) / n
@@ -127,6 +138,24 @@ def reduce(devices: Sequence[DeviceTrace], host_spans: Sequence[Named],
     return TraceSummary(window_s=hi - lo, busy_s=busy_total / n,
                         n_devices=len(devices), module_s=dict(module_s),
                         op_s=dict(op_s), gap_s=dict(gap_s))
+
+
+def busy_intervals(dev: DeviceTrace, lo: float, hi: float
+                   ) -> List[Interval]:
+    """Merged intervals within ``[lo, hi]`` in which ``dev`` ran an
+    operation (its programs, where the trace has no op line)."""
+    return merge(clip(((a, b) for _, a, b in dev.ops or dev.modules),
+                      lo, hi))
+
+
+def counts(devices: Sequence[DeviceTrace], host_spans: Sequence[Named],
+           window: Interval) -> Tuple[int, int]:
+    """The idle gaps (over all devices) and host spans that ``reduce``
+    attributes: its cost is in proportion to these."""
+    lo, hi = window
+    n_gaps = sum(len(gaps(busy_intervals(d, lo, hi), lo, hi))
+                 for d in devices)
+    return n_gaps, sum(s[0] != WINDOW_SPAN for s in host_spans)
 
 
 def clip3(events: Iterable[Named], lo: float, hi: float) -> List[Named]:
