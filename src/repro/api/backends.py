@@ -219,26 +219,34 @@ def analog_pallas_packed(state, lits: jax.Array,
                                 CAP_PACKED_IO, CAP_PACKED_PLANES},
                   priority=40, predicate=lambda s: s.plane_packed,
                   dot_mode=lambda s, keyed: ops.planes_dot_mode(
-                      s.plane_dev, s.vcfg, keyed=keyed))
+                      s.plane_dev, s.vcfg, keyed=keyed),
+                  column_operand=lambda s, keyed, **tiles:
+                      ops.planes_column_source(s.plane_dev, s.col_diff_t,
+                                               s.vcfg, keyed=keyed,
+                                               **tiles))
 def analog_pallas_packed2(state, lits: jax.Array,
                           key: Optional[jax.Array] = None,
                           **tiles) -> jax.Array:
     """Plane-packed analog kernel: the resident conductance stack stays
     compressed in HBM (LRS/HRS index bitplane + additive deviation
-    plane, elided when nominal) and the kernel reconstructs ``g``/
-    ``leak`` tiles in VMEM behind double-buffered HBM->VMEM DMA.  Noise
+    plane, elided when nominal); the kernel reconstructs a nominal
+    chip's ``g``/``leak`` tiles in VMEM behind double-buffered HBM->VMEM
+    DMA, and streams a deviating chip's column operand (programmed, or
+    built per read from a fresh C2C draw) the same way.  Noise
     semantics == ``analog-pallas-packed`` (C2C per read, scalar v_ref —
     no CSA offset, so those reads fall back loudly)."""
     litw = _as_packed_lits(lits)
     l_valid = int(state.include.shape[-1])
+    cols = dict(col_diff_t=state.col_diff_t, col_leak_t=state.col_leak_t)
     if isinstance(state, ReplicaStackState):
         return _to_i32(ops.imbue_class_sums_stack_planes(
             litw, state.plane_index, state.plane_dev, state.icfg,
             state.tm_cfg, key, vcfg=state.vcfg, l_valid=l_valid,
-            n_replicas=state.n_replicas, **tiles))
+            n_replicas=state.n_replicas, **cols, **tiles))
     return _to_i32(ops.imbue_class_sums_planes(
         litw, state.plane_index, state.plane_dev, state.icfg,
-        state.tm_cfg, key, vcfg=state.vcfg, l_valid=l_valid, **tiles))
+        state.tm_cfg, key, vcfg=state.vcfg, l_valid=l_valid, **cols,
+        **tiles))
 
 
 # ----------------------------------------------------------- coalesced

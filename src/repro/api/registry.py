@@ -70,6 +70,11 @@ class Backend:
     # the reads carry a noise key).  None on paths without crossbar
     # currents.
     dot_mode: Optional[Callable] = None
+    # Where a read's programmed column operand comes from:
+    # ``column_operand(state, keyed, **tiles)`` -> ``"programmed"``,
+    # ``"built"`` or None (``ops.planes_column_source``).  None on paths
+    # that take no such operand.
+    column_operand: Optional[Callable] = None
 
     def accepts(self, state) -> bool:
         if not isinstance(state, self.state_types):
@@ -99,7 +104,7 @@ _REGISTRY: Dict[str, Backend] = {}
 
 def register_backend(name: str, *, state_types, capabilities,
                      priority: int = 0, doc: str = "", predicate=None,
-                     dot_mode=None):
+                     dot_mode=None, column_operand=None):
     """Decorator: register ``fn`` as backend ``name``."""
     unknown = frozenset(capabilities) - KNOWN_CAPABILITIES
     if unknown:
@@ -114,7 +119,7 @@ def register_backend(name: str, *, state_types, capabilities,
             capabilities=frozenset(capabilities), priority=priority,
             doc=doc or (fn.__doc__ or "").strip().splitlines()[0]
             if (doc or fn.__doc__) else "", predicate=predicate,
-            dot_mode=dot_mode)
+            dot_mode=dot_mode, column_operand=column_operand)
         return fn
 
     return deco

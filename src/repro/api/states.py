@@ -44,9 +44,25 @@ programmed conductance stack itself: ``state.pack_planes()`` attaches
   operand is then the index bitplane alone, ~64x smaller than the two
   f32 planes the dense kernels stream.
 
-The ``*-pallas-packed2`` backends reconstruct conductance tiles from
-these planes in VMEM (``CAP_PACKED_PLANES``) behind double-buffered
-HBM->VMEM DMA; nominal reconstruction is bit-exact by construction
+A deviating chip also carries its **programmed column operand**,
+built with the deviation plane (``ops.column_operand``) in the planes
+kernels' padded, transposed layout:
+
+* ``col_diff_t`` — ``v_read * g - leak`` per cell (``[L_pad, C_pad]`` /
+  ``[R, L_pad, C_pad]`` f32), the term the column dots contract;
+* ``col_leak_t`` — each 32-cell column's leak sum
+  (``[.., L_pad / 32, C_pad]`` f32).
+
+Both depend only on the programmed resistances, so a read that draws
+no fresh C2C noise streams them and the kernel only dots and compares
+(a keyed C2C read builds the pair from the deviation it draws); they
+are re-derived wherever the deviation plane is and dropped with it.
+
+The ``*-pallas-packed2`` backends reconstruct a nominal chip's
+conductance tiles from the index bitplane in VMEM
+(``CAP_PACKED_PLANES``) behind double-buffered HBM->VMEM DMA, and
+stream a deviating chip's column operand the same way; nominal
+reconstruction is bit-exact by construction
 (``dev == 0`` -> ``r = r_nom`` in exact f32 arithmetic).  Off-nominal,
 packing *quantizes* each resistance to its own reconstruction
 (``r := fl(r_nom + fl(r - r_nom))``, at most 0.5 ulp, far below
@@ -68,7 +84,7 @@ from repro.core.coalesced import CoalescedConfig
 from repro.core.imbue import IMBUEConfig, ProgrammedCrossbar
 from repro.core.mapping import CrossbarMapping
 from repro.core.tm import TMConfig, include_mask
-from repro.kernels import bitpack
+from repro.kernels import bitpack, ops
 
 
 class _PackedMixin:
@@ -127,6 +143,18 @@ def _deviation_plane(r: jax.Array, include: jax.Array
     if not bool((dev != 0.0).any()):
         return r.astype(jnp.float32), None
     return (r_nom + dev).astype(jnp.float32), dev
+
+
+def _with_deviation(state, field: str, r: jax.Array):
+    """``state`` with resistances ``r`` stored in ``field``, quantized
+    to their own reconstruction, and the deviation plane and programmed
+    column operand derived from them (both None when nominal)."""
+    r_q, dev = _deviation_plane(r, state.include)
+    cols = (None, None) if dev is None else ops.column_operand(
+        state.plane_index, dev, state.icfg,
+        l_valid=int(state.include.shape[-1]))
+    return dataclasses.replace(state, **{field: r_q}, plane_dev=dev,
+                               col_diff_t=cols[0], col_leak_t=cols[1])
 
 
 def _register(cls, data_fields: Tuple[str, ...], meta_fields: Tuple[str, ...]):
@@ -195,19 +223,21 @@ class CrossbarState(_PackedMixin):
     fault_mask: Optional[jax.Array] = None       # [C, L] int8 fault codes
     plane_index: Optional[jax.Array] = None      # [C, L/32] uint32 LRS/HRS
     plane_dev: Optional[jax.Array] = None        # [C, L] f32 r - r_nom
+    col_diff_t: Optional[jax.Array] = None       # [L_pad, C_pad] on - leak
+    col_leak_t: Optional[jax.Array] = None       # [L_pad/32, C_pad] f32
 
     def pack_planes(self) -> "CrossbarState":
         """This chip with the resident conductance plane packed: the
-        LRS/HRS index bitplane plus the additive deviation plane
-        (elided for a nominal chip).  Implies :meth:`pack` — the index
-        bitplane IS the packed include plane, one shared buffer."""
+        LRS/HRS index bitplane plus the additive deviation plane and
+        its programmed column operand (all elided for a nominal chip).
+        Implies :meth:`pack` — the index bitplane IS the packed include
+        plane, one shared buffer."""
         if self.plane_packed:
             return self
         packed = self.pack()
-        r_q, dev = _deviation_plane(packed.r_mem, packed.include)
-        return dataclasses.replace(
-            packed, r_mem=r_q, plane_index=packed.include_packed,
-            plane_dev=dev)
+        return _with_deviation(dataclasses.replace(
+            packed, plane_index=packed.include_packed), "r_mem",
+            packed.r_mem)
 
     @classmethod
     def program(cls, include: jax.Array, key: jax.Array, tm_cfg: TMConfig,
@@ -252,7 +282,8 @@ class CrossbarState(_PackedMixin):
         r_mem = var.sample_device_resistance(key, include, self.vcfg)
         return dataclasses.replace(self, r_mem=r_mem, include=include,
                                    include_packed=None, fault_mask=None,
-                                   plane_index=None, plane_dev=None)
+                                   plane_index=None, plane_dev=None,
+                                   col_diff_t=None, col_leak_t=None)
 
     def inject_faults(self, key: jax.Array,
                       fcfg: Optional[var.FaultConfig] = None
@@ -278,11 +309,11 @@ class CrossbarState(_PackedMixin):
         out = dataclasses.replace(self, r_mem=r_mem, fault_mask=mask)
         if self.plane_packed:
             # The fault overlay changed resistances, not TA actions: the
-            # index bitplane stays valid and the deviation plane
-            # re-derives from the injured resistances — keeping the old
-            # one would silently serve healthy values.
-            r_q, dev = _deviation_plane(r_mem, self.include)
-            out = dataclasses.replace(out, r_mem=r_q, plane_dev=dev)
+            # index bitplane stays valid and the deviation plane (with
+            # its column operand) re-derives from the injured
+            # resistances — keeping the old one would silently serve
+            # healthy values.
+            out = _with_deviation(out, "r_mem", r_mem)
         return out
 
 
@@ -302,20 +333,22 @@ class ReplicaStackState(_PackedMixin):
     fault_mask: Optional[jax.Array] = None       # [R, C, L] int8 fault codes
     plane_index: Optional[jax.Array] = None      # [C, L/32] uint32 LRS/HRS
     plane_dev: Optional[jax.Array] = None        # [R, C, L] f32 r - r_nom
+    col_diff_t: Optional[jax.Array] = None       # [R, L_pad, C_pad] f32
+    col_leak_t: Optional[jax.Array] = None       # [R, L_pad/32, C_pad] f32
 
     def pack_planes(self) -> "ReplicaStackState":
         """The stack with the resident conductance planes packed: ONE
         shared LRS/HRS index bitplane (TA actions are shared) plus the
-        per-replica additive deviation plane — elided entirely for a
-        nominal stack, where all R chips' resident operand collapses to
-        the single index bitplane.  Implies :meth:`pack`."""
+        per-replica additive deviation plane and programmed column
+        operand — elided entirely for a nominal stack, where all R
+        chips' resident operand collapses to the single index bitplane.
+        Implies :meth:`pack`."""
         if self.plane_packed:
             return self
         packed = self.pack()
-        r_q, dev = _deviation_plane(packed.r_stack, packed.include)
-        return dataclasses.replace(
-            packed, r_stack=r_q, plane_index=packed.include_packed,
-            plane_dev=dev)
+        return _with_deviation(dataclasses.replace(
+            packed, plane_index=packed.include_packed), "r_stack",
+            packed.r_stack)
 
     @classmethod
     def program(cls, include: jax.Array, key: jax.Array, n_replicas: int,
@@ -347,12 +380,13 @@ class ReplicaStackState(_PackedMixin):
     def replica_slice(self, i: int) -> "ReplicaStackState":
         """Single-chip view ``[1, C, L]`` — shape is replica-independent,
         so routed dispatch reuses one compiled kernel for every chip."""
-        fm = (None if self.fault_mask is None
-              else self.fault_mask[i:i + 1])
-        pd = (None if self.plane_dev is None
-              else self.plane_dev[i:i + 1])
-        return dataclasses.replace(self, r_stack=self.r_stack[i:i + 1],
-                                   fault_mask=fm, plane_dev=pd)
+        def one(a):
+            return None if a is None else a[i:i + 1]
+
+        return dataclasses.replace(
+            self, r_stack=self.r_stack[i:i + 1],
+            fault_mask=one(self.fault_mask), plane_dev=one(self.plane_dev),
+            col_diff_t=one(self.col_diff_t), col_leak_t=one(self.col_leak_t))
 
     @property
     def is_sharded(self) -> bool:
@@ -373,12 +407,16 @@ class ReplicaStackState(_PackedMixin):
 
     def replica(self, i: int) -> CrossbarState:
         """Chip ``i`` as a standalone ``CrossbarState``."""
-        fm = None if self.fault_mask is None else self.fault_mask[i]
-        pd = None if self.plane_dev is None else self.plane_dev[i]
+        def one(a):
+            return None if a is None else a[i]
+
         return CrossbarState(r_mem=self.r_stack[i], include=self.include,
                              tm_cfg=self.tm_cfg, icfg=self.icfg,
-                             vcfg=self.vcfg, fault_mask=fm,
-                             plane_index=self.plane_index, plane_dev=pd)
+                             vcfg=self.vcfg, fault_mask=one(self.fault_mask),
+                             plane_index=self.plane_index,
+                             plane_dev=one(self.plane_dev),
+                             col_diff_t=one(self.col_diff_t),
+                             col_leak_t=one(self.col_leak_t))
 
     def reprogram(self, include: jax.Array,
                   key: jax.Array) -> "ReplicaStackState":
@@ -399,7 +437,8 @@ class ReplicaStackState(_PackedMixin):
         )(keys)
         return dataclasses.replace(self, r_stack=r_stack, include=include,
                                    include_packed=None, fault_mask=None,
-                                   plane_index=None, plane_dev=None)
+                                   plane_index=None, plane_dev=None,
+                                   col_diff_t=None, col_leak_t=None)
 
     def inject_faults(self, key: jax.Array,
                       fcfg: Optional[var.FaultConfig] = None,
@@ -432,8 +471,7 @@ class ReplicaStackState(_PackedMixin):
         if self.plane_packed:
             # Same rule as CrossbarState: actions (index bitplane)
             # unchanged, deviations re-derived from the injured stack.
-            r_q, dev = _deviation_plane(injured, self.include)
-            out = dataclasses.replace(out, r_stack=r_q, plane_dev=dev)
+            out = _with_deviation(out, "r_stack", injured)
         return out
 
 
@@ -543,10 +581,12 @@ class CoalescedState(_PackedMixin):
 _register(DigitalState, ("include", "ta_state", "include_packed"),
           ("tm_cfg",))
 _register(CrossbarState, ("r_mem", "include", "include_packed",
-                          "fault_mask", "plane_index", "plane_dev"),
+                          "fault_mask", "plane_index", "plane_dev",
+                          "col_diff_t", "col_leak_t"),
           ("tm_cfg", "icfg", "vcfg"))
 _register(ReplicaStackState, ("r_stack", "include", "include_packed",
-                              "fault_mask", "plane_index", "plane_dev"),
+                              "fault_mask", "plane_index", "plane_dev",
+                              "col_diff_t", "col_leak_t"),
           ("tm_cfg", "icfg", "vcfg"))
 _register(CoalescedState, ("ta_state", "weights", "include_packed",
                            "fault_mask", "plane_index"),

@@ -113,54 +113,40 @@ DOTS_BF16X3 = "bf16x3"          # 0/1 literals x 3 bf16 terms, f32 sums
 
 
 def dot_mode(has_dev: bool) -> str:
-    """The column-dot mode of the plane-packed kernel for a chip with
-    (``has_dev``) or without a deviation plane."""
+    """The column-dot mode of a plane-packed read of a chip that senses
+    a deviation (``has_dev``: the programmed-operand kernel) or none
+    (the nominal planes kernel)."""
     return DOTS_BF16X3 if has_dev else DOTS_DEFAULT
 
 
-def imbue_infer_planes_kernel(*refs, width, cols_per_block, nk, has_dev):
-    """Plane-packed variant: the conductance stack never reaches the
-    kernel as f32.  It arrives as (a) the LRS/HRS include-index bitplane
-    — ``[Lw, C] uint32``, 32x smaller than either f32 plane — and
-    optionally (b) a per-cell additive resistance-deviation plane
-    (D2D draws and fault overlays fold into it; it is elided entirely
-    for nominal stacks).  Both stay in ANY/HBM memory space; the kernel
-    DMAs one K-chunk at a time into a 2-slot VMEM scratch and starts
-    chunk ``k+1``'s copy before computing chunk ``k`` — the same
-    compute/transfer overlap ``AsyncServeEngine`` plays at the host,
-    pushed into the kernel.
+def imbue_infer_planes_kernel(scal_ref, litw_ref, incw_hbm, pol_ref,
+                              out_ref, and_ref, *, width, cols_per_block,
+                              nk):
+    """Plane-packed variant for a nominal chip: the conductance plane
+    never reaches the kernel as f32.  It arrives as the LRS/HRS
+    include-index bitplane — ``[Lw, C] uint32``, 32x smaller than either
+    f32 plane — in ANY/HBM memory space; the kernel DMAs one K-chunk at
+    a time into a 2-slot VMEM scratch and starts chunk ``k+1``'s copy
+    before computing chunk ``k`` — the same compute/transfer overlap
+    ``AsyncServeEngine`` plays at the host, pushed into the kernel.
 
     Per chunk the conductance/leak tiles are RECONSTRUCTED in VMEM with
     the exact op order of ``core.imbue.conductances``::
 
-        r_nom = bits * r_lrs + (1 - bits) * r_hrs      # exact 0/1 select
-        r     = r_nom + dev                            # dev = r - r_nom
+        r     = bits * r_lrs + (1 - bits) * r_hrs      # exact 0/1 select
         g     = 1 / (series_factor * r)
-        leak  = leak_nom * (r_nom / r)
+        leak  = leak_nom * (r / r)
 
-    so nominal (dev == 0) results are bit-identical to the f32-plane
-    kernels.  Word-padded columns past ``l_valid`` would otherwise
-    reconstruct as HRS cells (the f32 path zero-pads them away), so an
-    in-kernel validity mask zeroes their ``g``/``leak`` contributions.
+    so results are bit-identical to the f32-plane kernels.  Word-padded
+    columns past ``l_valid`` would otherwise reconstruct as HRS cells
+    (the f32 path zero-pads them away), so an in-kernel validity mask
+    zeroes their ``g``/``leak`` contributions.  Every column current is
+    a class-nominal value, 11% from the reference, so the one-pass dots
+    of the f32-plane kernels (:data:`DOTS_DEFAULT`) sense exactly.
 
-    The column dots follow ``has_dev`` (:func:`dot_mode`): a deviating
-    chip's currents are summed from f32 products (``exact_columns``),
-    since D2D draws put some columns within a bfloat16 rounding of the
-    reference; a nominal chip keeps the one-pass dots of the f32-plane
-    kernels, whose currents sit 11% from the reference on either side.
-
-    Grid is (replica, B block, C block): replica ``r`` reads its own
-    ``[L, C]`` slab of the ``[R, L, C]`` deviation stack, so a whole
-    replica stack is one kernel call (a vmap would instead batch the
-    ANY-space operand, which Mosaic cannot lower).
+    A deviating chip (D2D draws, fault overlays or a C2C read) streams
+    its column operand instead (:func:`imbue_infer_programmed_kernel`).
     """
-    if has_dev:
-        (scal_ref, litw_ref, incw_hbm, dev_hbm, pol_ref,
-         out_ref, and_ref) = refs
-    else:
-        scal_ref, litw_ref, incw_hbm, pol_ref, out_ref, and_ref = refs
-        dev_hbm = None
-    r_idx = pl.program_id(0)
     j = pl.program_id(2)
 
     i_ref = scal_ref[0]
@@ -178,50 +164,20 @@ def imbue_infer_planes_kernel(*refs, width, cols_per_block, nk, has_dev):
 
     and_ref[...] = jnp.ones_like(and_ref)
 
-    def exact_columns(bits, on, leak):
-        """Column currents in f32 for a deviating chip.  A cell carries
-        ``on`` when its literal is 0 and ``leak`` when it is 1, so a
-        column reads ``sum(leak) + lit0 . (on - leak)``.  ``lit0`` is 0
-        or 1, exact in bf16, and ``on - leak`` is split into three bf16
-        terms (8 + 8 + 8 bits of its f32 significand), so three
-        single-pass dots give the f32 products, accumulated in f32."""
-        d = on - leak
-        d_hi = d.astype(jnp.bfloat16)
-        rest = d - d_hi.astype(jnp.float32)
-        d_mid = rest.astype(jnp.bfloat16)
-        d_lo = (rest - d_mid.astype(jnp.float32)).astype(jnp.bfloat16)
-        lit0 = (1.0 - bits).astype(jnp.bfloat16)
-        for w in range(cols_per_block):
-            lo, hi = w * width, (w + 1) * width
-            x = lit0[:, lo:hi]
-            i_col = (jnp.sum(leak[lo:hi, :], axis=0, keepdims=True)
-                     + (jnp.dot(x, d_hi[lo:hi, :],
-                                preferred_element_type=jnp.float32)
-                        + jnp.dot(x, d_mid[lo:hi, :],
-                                  preferred_element_type=jnp.float32)
-                        + jnp.dot(x, d_lo[lo:hi, :],
-                                  preferred_element_type=jnp.float32)))
-            and_ref[...] *= (i_col < i_ref).astype(jnp.float32)
-
-    def compute_chunk(k, inc_words, dev_tile):
+    def compute_chunk(k, inc_words):
         bits_inc = unpack_words_f32_cols(inc_words, n_bits=kt)  # [kt, ct]
-        r_nom = bits_inc * r_lrs + (1.0 - bits_inc) * r_hrs
-        r = r_nom if dev_tile is None else r_nom + dev_tile
+        r = bits_inc * r_lrs + (1.0 - bits_inc) * r_hrs
         # Mask word-padding columns (>= l_valid): the f32 path zero-pads
         # their g/leak rows; reconstruction must not resurrect them.
         row = jax.lax.broadcasted_iota(jnp.int32, (kt, ct), 0)
         valid = (k * kt + row).astype(jnp.float32) < l_valid
         g = jnp.where(valid, 1.0 / (series_factor * r), 0.0)
         leak_nom = jnp.where(bits_inc > 0.5, leak_inc, leak_exc)
-        leak = jnp.where(valid, leak_nom * (r_nom / r), 0.0)
+        # r_nom / r of core.imbue.conductances with r == r_nom: kept, not
+        # folded to 1, so the op order (and rounding) stays the same.
+        leak = jnp.where(valid, leak_nom * (r / r), 0.0)
 
         bits = unpack_words_f32(litw_ref[k], n_bits=kt)         # [bt, kt]
-        if dev_tile is not None:
-            exact_columns(bits, v_read * g, leak)
-            return
-        # Nominal chip: every column current is a class-nominal value,
-        # 11% from the reference, so the one-pass dots of the f32-plane
-        # kernels (operands rounded by at most 0.2%) sense exactly.
         v_drive = (1.0 - bits) * v_read
         for w in range(cols_per_block):
             lo, hi = w * width, (w + 1) * width
@@ -232,20 +188,13 @@ def imbue_infer_planes_kernel(*refs, width, cols_per_block, nk, has_dev):
             partial_cl = (i_on + i_leak) < i_ref
             and_ref[...] *= partial_cl.astype(jnp.float32)
 
-    def body(inc_scr, inc_sem, dev_scr=None, dev_sem=None):
-        def copies(slot, k):
-            cps = [pltpu.make_async_copy(
+    def body(inc_scr, inc_sem):
+        def copy(slot, k):
+            return pltpu.make_async_copy(
                 incw_hbm.at[pl.dslice(k * kw, kw), pl.dslice(j * ct, ct)],
-                inc_scr.at[slot], inc_sem.at[slot])]
-            if has_dev:
-                cps.append(pltpu.make_async_copy(
-                    dev_hbm.at[r_idx, pl.dslice(k * kt, kt),
-                               pl.dslice(j * ct, ct)],
-                    dev_scr.at[slot], dev_sem.at[slot]))
-            return cps
+                inc_scr.at[slot], inc_sem.at[slot])
 
-        for cp in copies(0, 0):
-            cp.start()
+        copy(0, 0).start()
 
         def loop(k, carry):
             slot = k % 2
@@ -253,27 +202,17 @@ def imbue_infer_planes_kernel(*refs, width, cols_per_block, nk, has_dev):
 
             @pl.when(nxt < nk)
             def _prefetch():
-                for cp in copies(nxt % 2, nxt):
-                    cp.start()
+                copy(nxt % 2, nxt).start()
 
-            for cp in copies(slot, k):
-                cp.wait()
-            compute_chunk(k, inc_scr[slot],
-                          dev_scr[slot] if has_dev else None)
+            copy(slot, k).wait()
+            compute_chunk(k, inc_scr[slot])
             return carry
 
         jax.lax.fori_loop(0, nk, loop, 0)
 
-    if has_dev:
-        pl.run_scoped(body,
-                      inc_scr=pltpu.VMEM((2, kw, ct), jnp.uint32),
-                      inc_sem=pltpu.SemaphoreType.DMA((2,)),
-                      dev_scr=pltpu.VMEM((2, kt, ct), jnp.float32),
-                      dev_sem=pltpu.SemaphoreType.DMA((2,)))
-    else:
-        pl.run_scoped(body,
-                      inc_scr=pltpu.VMEM((2, kw, ct), jnp.uint32),
-                      inc_sem=pltpu.SemaphoreType.DMA((2,)))
+    pl.run_scoped(body,
+                  inc_scr=pltpu.VMEM((2, kw, ct), jnp.uint32),
+                  inc_sem=pltpu.SemaphoreType.DMA((2,)))
 
     @pl.when(j == 0)
     def _init_out():
@@ -283,20 +222,18 @@ def imbue_infer_planes_kernel(*refs, width, cols_per_block, nk, has_dev):
                             preferred_element_type=jnp.float32)
 
 
-def imbue_infer_planes_call(litw, incw_t, dev_t, pol, v_ref, v_read, *,
+def imbue_infer_planes_call(litw, incw_t, pol, v_ref, v_read, *,
                             width, r_div, r_lrs, r_hrs, leak_inc, leak_exc,
                             series_factor, l_valid, bt, ct, kt, interpret):
-    """``[B, L/32] -> [R, B, M]`` analog class sums from packed literals
-    AND a plane-packed conductance stack.
+    """``[B, L/32] -> [1, B, M]`` analog class sums from packed literals
+    AND a nominal chip's include-index bitplane.
 
-    ``incw_t`` is the transposed include-index bitplane ``[Lw, C]``
-    uint32 (bit ``j`` of word row ``w`` = literal ``32*w + j``), shared
-    by every replica; ``dev_t`` is the transposed additive deviation
-    stack ``[R, L, C]`` f32, or None for a nominal (index-only) chip
-    (then ``R == 1``).  ``kt`` counts bits and must be a multiple of
-    both ``width`` and 32.  The K dimension is streamed *inside* the
-    kernel with double-buffered HBM->VMEM copies, so the grid is
-    (R, B, C) blocks.
+    ``incw_t`` is the transposed bitplane ``[Lw, C]`` uint32 (bit ``j``
+    of word row ``w`` = literal ``32*w + j``).  ``kt`` counts bits and
+    must be a multiple of both ``width`` and 32.  The K dimension is
+    streamed *inside* the kernel with double-buffered HBM->VMEM copies,
+    so the grid is (1, B, C) blocks, the leading axis the replica axis
+    of :func:`imbue_infer_programmed_call`.
     """
     if kt % width:
         raise ValueError(f"kt={kt} must be a multiple of width={width}")
@@ -311,42 +248,170 @@ def imbue_infer_planes_call(litw, incw_t, dev_t, pol, v_ref, v_read, *,
                          f"include bitplane has {incw_t.shape[0]}")
     if lw % kw:
         raise ValueError(f"word rows {lw} not divisible by kt/32={kw}")
-    has_dev = dev_t is not None
-    if has_dev and dev_t.shape[1:] != (lw * WORD, c):
-        raise ValueError(f"dev planes {dev_t.shape[1:]} != "
-                         f"{(lw * WORD, c)}")
-    n_rep = dev_t.shape[0] if has_dev else 1
     nk = lw // kw
-    grid = (n_rep, b // bt, c // ct)
     kern = partial(imbue_infer_planes_kernel, width=width,
-                   cols_per_block=kt // width, nk=nk, has_dev=has_dev)
+                   cols_per_block=kt // width, nk=nk)
     scal = jnp.asarray([v_ref / r_div, v_read, r_lrs, r_hrs, leak_inc,
                         leak_exc, series_factor, float(l_valid)],
                        dtype=jnp.float32)
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),                # scalars
-        pl.BlockSpec((nk, bt, kw),                            # literal words
-                     lambda r, i, j: (0, i, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),                    # include plane
-    ]
-    operands = [scal, word_chunks(litw, kw)]
-    if has_dev:
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))    # dev planes
-        operands += [incw_t, dev_t, pol]
-    else:
-        operands += [incw_t, pol]
-    in_specs.append(pl.BlockSpec((ct, m), lambda r, i, j: (j, 0)))  # pol
     return pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=in_specs,
+        grid=(1, b // bt, c // ct),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),            # scalars
+            pl.BlockSpec((nk, bt, kw),                        # literal words
+                         lambda r, i, j: (0, i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),                # include plane
+            pl.BlockSpec((ct, m), lambda r, i, j: (j, 0)),    # pol
+        ],
+        out_specs=pl.BlockSpec((None, bt, m), lambda r, i, j: (r, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, b, m), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bt, ct), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(scal, word_chunks(litw, kw), incw_t, pol)
+
+
+def imbue_infer_programmed_kernel(scal_ref, litw_ref, diff_hbm, leak_ref,
+                                  pol_ref, out_ref, and_ref, *, width,
+                                  cols_per_block, nk):
+    """Deviating-chip variant over a *programmed* column operand: the
+    per-cell ``on - leak`` plane (``[R, L, C]`` f32, streamed in
+    ``[kt, ct]`` chunks behind the same 2-slot HBM->VMEM DMA as
+    :func:`imbue_infer_planes_kernel`) and each column's leak sum
+    (``[R, L/kt, kt/width, C]``, one ``[L/kt, kt/width, ct]`` block per
+    grid step), both built by ``ops.column_operand``: once when the chip
+    is programmed, or per read for a fresh C2C draw.  The kernel only
+    splits, dots and compares: no include bitplane, no conductance
+    reconstruction.
+
+    A cell carries ``on`` when its literal is 0 and ``leak`` when it is
+    1, so a column reads ``sum(leak) + lit0 . (on - leak)``.  D2D draws
+    put some columns within a bfloat16 rounding of the reference, so the
+    currents are summed from f32 products (:data:`DOTS_BF16X3`):
+    ``lit0`` is 0 or 1, exact in bf16, and ``on - leak`` is split into
+    three bf16 terms (8 + 8 + 8 bits of its f32 significand), so three
+    single-pass dots give the f32 products, accumulated in f32.
+
+    Grid is (replica, B block, C block): replica ``r`` reads its own
+    ``[L, C]`` slab of the ``[R, L, C]`` operand, so a whole replica
+    stack is one kernel call (a vmap would instead batch the ANY-space
+    operand, which Mosaic cannot lower)."""
+    r_idx = pl.program_id(0)
+    j = pl.program_id(2)
+    i_ref = scal_ref[0]
+    kt = cols_per_block * width
+    ct = and_ref.shape[1]
+
+    and_ref[...] = jnp.ones_like(and_ref)
+
+    def compute_chunk(k, d):
+        d_hi = d.astype(jnp.bfloat16)
+        rest = d - d_hi.astype(jnp.float32)
+        d_mid = rest.astype(jnp.bfloat16)
+        d_lo = (rest - d_mid.astype(jnp.float32)).astype(jnp.bfloat16)
+        bits = unpack_words_f32(litw_ref[k], n_bits=kt)         # [bt, kt]
+        lit0 = (1.0 - bits).astype(jnp.bfloat16)
+        leak = leak_ref[k]                                  # [kt/width, ct]
+        for w in range(cols_per_block):
+            lo, hi = w * width, (w + 1) * width
+            x = lit0[:, lo:hi]
+            i_col = (leak[w:w + 1, :]
+                     + (jnp.dot(x, d_hi[lo:hi, :],
+                                preferred_element_type=jnp.float32)
+                        + jnp.dot(x, d_mid[lo:hi, :],
+                                  preferred_element_type=jnp.float32)
+                        + jnp.dot(x, d_lo[lo:hi, :],
+                                  preferred_element_type=jnp.float32)))
+            and_ref[...] *= (i_col < i_ref).astype(jnp.float32)
+
+    def body(diff_scr, diff_sem):
+        def copy(slot, k):
+            return pltpu.make_async_copy(
+                diff_hbm.at[r_idx, pl.dslice(k * kt, kt),
+                            pl.dslice(j * ct, ct)],
+                diff_scr.at[slot], diff_sem.at[slot])
+
+        copy(0, 0).start()
+
+        def loop(k, carry):
+            slot = k % 2
+            nxt = k + 1
+
+            @pl.when(nxt < nk)
+            def _prefetch():
+                copy(nxt % 2, nxt).start()
+
+            copy(slot, k).wait()
+            compute_chunk(k, diff_scr[slot])
+            return carry
+
+        jax.lax.fori_loop(0, nk, loop, 0)
+
+    pl.run_scoped(body, diff_scr=pltpu.VMEM((2, kt, ct), jnp.float32),
+                  diff_sem=pltpu.SemaphoreType.DMA((2,)))
+
+    @pl.when(j == 0)
+    def _init_out():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    out_ref[...] += jnp.dot(and_ref[...], pol_ref[...],
+                            preferred_element_type=jnp.float32)
+
+
+def imbue_infer_programmed_call(litw, diff_t, leak_t, pol, v_ref, *, width,
+                                r_div, bt, ct, kt, interpret):
+    """``[B, L/32] -> [R, B, M]`` analog class sums from packed literals
+    AND a deviating stack's programmed column operand.
+
+    ``diff_t`` is ``[R, L, C]`` f32 (``v_read * g - leak`` per cell, 0
+    on padding), ``leak_t`` ``[R, L/width, C]`` f32 (each column's leak
+    sum); ``L`` must be a multiple of ``kt`` and ``C`` of ``ct``, and
+    ``litw`` covers ``L/32`` words.  Grid and output as
+    :func:`imbue_infer_planes_call`.
+    """
+    if kt % width:
+        raise ValueError(f"kt={kt} must be a multiple of width={width}")
+    if kt % WORD:
+        raise ValueError(f"kt={kt} must be a multiple of {WORD} (packed)")
+    kw = kt // WORD
+    kc = kt // width
+    b, lw = litw.shape
+    n_rep, l, c = diff_t.shape
+    m = pol.shape[1]
+    if l != lw * WORD:
+        raise ValueError(f"literal words cover {lw * WORD} bits but the "
+                         f"column operand has {l} rows")
+    if l % kt or c % ct:
+        raise ValueError(f"column operand {(l, c)} is not a multiple of "
+                         f"the tiles {(kt, ct)}")
+    if leak_t.shape != (n_rep, l // width, c):
+        raise ValueError(f"column leak sums {leak_t.shape} != "
+                         f"{(n_rep, l // width, c)}")
+    nk = l // kt
+    kern = partial(imbue_infer_programmed_kernel, width=width,
+                   cols_per_block=kc, nk=nk)
+    return pl.pallas_call(
+        kern,
+        grid=(n_rep, b // bt, c // ct),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),            # i_ref
+            pl.BlockSpec((nk, bt, kw),                        # literal words
+                         lambda r, i, j: (0, i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),                # on - leak
+            pl.BlockSpec((None, nk, kc, ct),                  # column leaks
+                         lambda r, i, j: (r, 0, 0, j)),
+            pl.BlockSpec((ct, m), lambda r, i, j: (j, 0)),    # pol
+        ],
         out_specs=pl.BlockSpec((None, bt, m), lambda r, i, j: (r, i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_rep, b, m), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bt, ct), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*operands)
+    )(jnp.asarray([v_ref / r_div], dtype=jnp.float32), word_chunks(litw, kw),
+      diff_t, leak_t.reshape(n_rep, nk, kc, c), pol)
 
 
 def imbue_infer_call(v_drive, lit1, g_t, leak_t, pol, v_ref, *,

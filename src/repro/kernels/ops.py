@@ -29,9 +29,13 @@ stack* is also compressed: an LRS/HRS include-index bitplane (32x
 smaller than one f32 plane) plus an optional per-cell additive
 resistance-deviation plane (``dev = r - r_nom``; D2D draws and fault
 overlays fold into it, nominal stacks elide it entirely), reconstructed
-in VMEM per K chunk behind double-buffered HBM->VMEM DMA:
+in VMEM per K chunk behind double-buffered HBM->VMEM DMA.  A deviating
+chip instead streams its column operand (``column_operand``: ``on -
+leak`` per cell and each column's leak sum), built once when the chip
+is programmed, or per read from a fresh C2C draw:
   ``imbue_class_sums_planes(litw, idx, dev, ...)``  -> [B, M]
   ``imbue_class_sums_stack_planes(litw, ...)``      -> [R, B, M]
+  ``column_operand(idx, dev, icfg, l_valid=...)``   -> programmed operand
   ``coalesced_class_sums_planes(litw, incw, w)``    -> [B, M] weighted tail
 
 Packed K tiles count bits and must be multiples of 32 (one uint32 word);
@@ -460,10 +464,101 @@ def planes_dot_mode(plane_dev, vcfg, *, keyed: bool) -> str:
     return _ai.dot_mode(_senses_dev(plane_dev, vcfg, keyed))
 
 
-def _planes_sums(litw, plane_index, devs, icfg, cfg, *, l_valid, bt, ct, kt,
+# Where a plane-packed read's column operand comes from
+# (``planes_column_source``).
+COLUMNS_PROGRAMMED = "programmed"   # the state's own, built at programming
+COLUMNS_BUILT = "built"             # built in the dispatch's own graph
+
+
+def planes_column_source(plane_dev, col_diff_t, vcfg, *, keyed: bool,
+                         ct: int = CT, kt: int = KT_ANALOG):
+    """Where the column operand of a plane-packed read comes from:
+    :data:`COLUMNS_PROGRAMMED` when the read senses the programmed
+    deviation and the state carries an operand whose padding the call's
+    ``ct``/``kt`` tiles divide; :data:`COLUMNS_BUILT` when the dispatch
+    must build it — from those tiles, or from a keyed C2C read's fresh
+    deviation draw; None for a nominal chip read without C2C, which
+    takes the index bitplane alone."""
+    if not _senses_dev(plane_dev, vcfg, keyed):
+        return None
+    if (not (keyed and vcfg.c2c) and col_diff_t is not None
+            and col_diff_t.shape[-2] % kt == 0
+            and col_diff_t.shape[-1] % ct == 0):
+        return COLUMNS_PROGRAMMED
+    return COLUMNS_BUILT
+
+
+@partial(jax.jit, static_argnames=("icfg", "l_valid", "ct", "kt"))
+def column_operand(plane_index: jax.Array, plane_dev: jax.Array, icfg, *,
+                   l_valid: int, ct: int = CT, kt: int = KT_ANALOG):
+    """The programmed column operand of a deviating chip (or stack), in
+    the planes kernels' layout: ``plane_dev`` ``[..., C, L]`` ->
+
+    * ``col_diff_t`` ``[..., L_pad, C_pad]`` f32: ``v_read * g - leak``
+      per cell, the term the column dots contract;
+    * ``col_leak_t`` ``[..., L_pad / width, C_pad]`` f32: the sum of
+      ``leak`` over each ``width``-cell column.
+
+    ``L`` pads to ``kt`` and ``C`` to ``ct``.  ``g`` and ``leak`` follow
+    the plane-packed kernel's reconstruction op for op (its validity
+    mask included), and padded rows and columns are exactly 0, so every
+    padding a multiple of the call's tiles reads the same currents.
+    Both depend only on the sensed deviation: a read without fresh C2C
+    noise streams the chip's programmed pair instead of building one
+    per dispatch."""
+    from repro.core.variations import (HRS_MEAN_OHM, I_LEAK_EXCLUDE,
+                                       I_LEAK_INCLUDE, LRS_MEAN_OHM)
+    kw = kt // bitpack.WORD
+    n_clauses = plane_index.shape[0]
+    incw_t = _pad_to(_pad_to(plane_index.astype(jnp.uint32), 0, ct),
+                     1, kw).T                               # [Lw_pad, C_pad]
+    l_pad, c_pad = incw_t.shape[0] * bitpack.WORD, incw_t.shape[1]
+    bits = bitpack.unpack_words_f32_cols(incw_t, n_bits=l_pad)
+    dev = jnp.swapaxes(_pad_to(_pad_to(plane_dev.astype(jnp.float32), -2, ct),
+                               -1, kt), -1, -2)            # [..., L_pad, C_pad]
+    r_nom = bits * LRS_MEAN_OHM + (1.0 - bits) * HRS_MEAN_OHM
+    r = r_nom + dev
+    row = jax.lax.broadcasted_iota(jnp.int32, (l_pad, c_pad), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (l_pad, c_pad), 1)
+    valid = (row < l_valid) & (col < n_clauses)
+    g = jnp.where(valid, 1.0 / (icfg.series_factor * r), 0.0)
+    leak_nom = jnp.where(bits > 0.5, I_LEAK_INCLUDE, I_LEAK_EXCLUDE)
+    leak = jnp.where(valid, leak_nom * (r_nom / r), 0.0)
+    col_leak = leak.reshape(*leak.shape[:-2], l_pad // icfg.width,
+                            icfg.width, c_pad).sum(axis=-2)
+    return icfg.v_read * g - leak, col_leak
+
+
+def _planes_polarity(cfg, plane_index, rows):
+    """The signed one-hot ``[C, M_pad]`` with empty clauses zeroed (from
+    the index bitplane), clause rows padded to a multiple of ``rows``."""
+    pol = polarity_matrix(cfg)
+    pol = pol * _nonempty_from_packed(
+        plane_index)[:, None].astype(jnp.float32)
+    return _pad_to(pol, 0, rows)
+
+
+def _programmed_sums(litw, plane_index, cols, icfg, cfg, *, bt, ct, kt,
+                     interpret):
+    """One programmed-operand kernel call: ``cols`` =
+    ``(col_diff_t [R, L_pad, C_pad], col_leak_t)`` -> ``[R, B, M]``."""
+    interp = (not _on_tpu()) if interpret is None else interpret
+    diff_t, leak_t = cols
+    l_pad, c_pad = diff_t.shape[-2:]
+    b = litw.shape[0]
+    litw_p = _pad_to(_pad_to(litw.astype(jnp.uint32), 0, bt), 1,
+                     l_pad // bitpack.WORD)
+    out = _ai.imbue_infer_programmed_call(
+        litw_p, diff_t, leak_t, _planes_polarity(cfg, plane_index, c_pad),
+        icfg.reference_voltage(), width=icfg.width, r_div=icfg.r_divider,
+        bt=bt, ct=ct, kt=kt, interpret=interp)
+    return out[:, :b, :cfg.n_classes]
+
+
+def _planes_sums(litw, plane_index, icfg, cfg, *, l_valid, bt, ct, kt,
                  interpret):
-    """One plane-packed kernel call: ``devs`` ``[R, C, L]`` (or None for
-    a nominal chip, ``R = 1``) -> ``[R, B, M]`` class sums."""
+    """One plane-packed kernel call for a nominal chip -> ``[1, B, M]``
+    class sums."""
     from repro.core.variations import (HRS_MEAN_OHM, I_LEAK_EXCLUDE,
                                        I_LEAK_INCLUDE, LRS_MEAN_OHM)
     interp = (not _on_tpu()) if interpret is None else interpret
@@ -472,15 +567,9 @@ def _planes_sums(litw, plane_index, devs, icfg, cfg, *, l_valid, bt, ct, kt,
     litw_p = _pad_to(_pad_to(litw.astype(jnp.uint32), 0, bt), 1, kw)
     incw_t = _pad_to(_pad_to(plane_index.astype(jnp.uint32), 0, ct),
                      1, kw).T
-    dev_t = (None if devs is None else
-             _pad_to(_pad_to(devs.astype(jnp.float32), 1, ct), 2, kt
-                     ).transpose(0, 2, 1))
-    pol = polarity_matrix(cfg)
-    pol = pol * _nonempty_from_packed(
-        plane_index)[:, None].astype(jnp.float32)
-    pol = _pad_to(pol, 0, ct)
+    pol = _planes_polarity(cfg, plane_index, ct)
     out = _ai.imbue_infer_planes_call(
-        litw_p, incw_t, dev_t, pol, icfg.reference_voltage(), icfg.v_read,
+        litw_p, incw_t, pol, icfg.reference_voltage(), icfg.v_read,
         width=icfg.width, r_div=icfg.r_divider, r_lrs=LRS_MEAN_OHM,
         r_hrs=HRS_MEAN_OHM, leak_inc=I_LEAK_INCLUDE,
         leak_exc=I_LEAK_EXCLUDE, series_factor=icfg.series_factor,
@@ -500,30 +589,47 @@ def imbue_class_sums_planes(
     *,
     vcfg=None,
     l_valid: int,
+    col_diff_t: jax.Array | None = None,  # [L_pad, C_pad] programmed operand
+    col_leak_t: jax.Array | None = None,  # [L_pad / width, C_pad]
     bt: int = BT, ct: int = CT, kt: int = KT_ANALOG,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused analog inference from a plane-packed chip -> ``[B, M]``.
 
-    The resident operand is the include-index bitplane plus (if any cell
-    deviates from its class-nominal resistance) the additive deviation
-    plane; the kernel reconstructs ``g``/``leak`` tiles in VMEM with the
-    exact ``core.imbue.conductances`` op order, so nominal results are
+    A nominal chip's resident operand is the include-index bitplane; the
+    kernel reconstructs ``g``/``leak`` tiles in VMEM with the exact
+    ``core.imbue.conductances`` op order, so its results are
     bit-identical to :func:`imbue_class_sums_raw_packed` on the dense
     planes.  ``l_valid`` is the true (unpadded) literal count — the
     kernel masks word-padding columns that the dense path zero-pads.
 
+    A chip whose cells deviate from their class-nominal resistance
+    (the additive deviation plane) streams its column operand
+    (:func:`column_operand`) instead: the programmed
+    ``col_diff_t``/``col_leak_t`` where the read senses the programmed
+    deviation and the tiles divide their padding, else one built here
+    (:func:`planes_column_source`).
+
     C2C noise (``key`` + ``vcfg.c2c``) is drawn per read in jnp before
-    the kernel (:func:`_read_dev`).  The CSA offset is NOT modeled
+    the operand is built (:func:`_read_dev`).  The CSA offset is NOT modeled
     (scalar reference), exactly like the dense analog kernels —
     capability selection routes those reads elsewhere.
     """
     from repro.core.variations import VariationConfig
     vcfg = vcfg or VariationConfig.nominal()
-    dev = _read_dev(plane_index, plane_dev, key, vcfg, l_valid)
-    return _planes_sums(litw, plane_index, None if dev is None else dev[None],
-                        icfg, cfg, l_valid=l_valid, bt=bt, ct=ct, kt=kt,
-                        interpret=interpret)[0]
+    source = planes_column_source(plane_dev, col_diff_t, vcfg,
+                                  keyed=key is not None, ct=ct, kt=kt)
+    if source is None:
+        return _planes_sums(litw, plane_index, icfg, cfg, l_valid=l_valid,
+                            bt=bt, ct=ct, kt=kt, interpret=interpret)[0]
+    if source == COLUMNS_PROGRAMMED:
+        cols = col_diff_t[None], col_leak_t[None]
+    else:
+        dev = _read_dev(plane_index, plane_dev, key, vcfg, l_valid)
+        cols = column_operand(plane_index, dev[None], icfg,
+                              l_valid=l_valid, ct=ct, kt=kt)
+    return _programmed_sums(litw, plane_index, cols, icfg, cfg, bt=bt,
+                            ct=ct, kt=kt, interpret=interpret)[0]
 
 
 @partial(jax.jit, static_argnames=("icfg", "cfg", "vcfg", "l_valid",
@@ -540,6 +646,8 @@ def imbue_class_sums_stack_planes(
     vcfg=None,
     l_valid: int,
     n_replicas: int,
+    col_diff_t: jax.Array | None = None,  # [R, L_pad, C_pad] programmed
+    col_leak_t: jax.Array | None = None,  # [R, L_pad / width, C_pad]
     bt: int = BT, ct: int = CT, kt: int = KT_ANALOG,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -552,25 +660,34 @@ def imbue_class_sums_stack_planes(
     draw per replica per read from the split of ``key``.  The whole
     stack is ONE kernel call with a replica grid axis; a nominal stack
     with no C2C read is one single-replica call broadcast over R —
-    replicas are bit-identical by construction.
+    replicas are bit-identical by construction.  A deviating stack
+    streams its column operand, programmed or built, as
+    :func:`imbue_class_sums_planes` does.
     """
     from repro.core.variations import VariationConfig
     vcfg = vcfg or VariationConfig.nominal()
-    opts = dict(l_valid=l_valid, bt=bt, ct=ct, kt=kt, interpret=interpret)
-    if not _senses_dev(plane_dev, vcfg, key is not None):
-        out = _planes_sums(litw, plane_index, None, icfg, cfg, **opts)
+    source = planes_column_source(plane_dev, col_diff_t, vcfg,
+                                  keyed=key is not None, ct=ct, kt=kt)
+    if source is None:
+        out = _planes_sums(litw, plane_index, icfg, cfg, l_valid=l_valid,
+                           bt=bt, ct=ct, kt=kt, interpret=interpret)
         return jnp.broadcast_to(out, (n_replicas,) + out.shape[1:])
-    if key is not None and vcfg.c2c:
-        keys = jax.random.split(key, n_replicas)
-        if plane_dev is None:
-            devs = jax.vmap(lambda k: _read_dev(
-                plane_index, None, k, vcfg, l_valid))(keys)
-        else:
-            devs = jax.vmap(lambda d, k: _read_dev(
-                plane_index, d, k, vcfg, l_valid))(plane_dev, keys)
+    if source == COLUMNS_PROGRAMMED:
+        cols = col_diff_t, col_leak_t
     else:
         devs = plane_dev
-    return _planes_sums(litw, plane_index, devs, icfg, cfg, **opts)
+        if key is not None and vcfg.c2c:
+            keys = jax.random.split(key, n_replicas)
+            if plane_dev is None:
+                devs = jax.vmap(lambda k: _read_dev(
+                    plane_index, None, k, vcfg, l_valid))(keys)
+            else:
+                devs = jax.vmap(lambda d, k: _read_dev(
+                    plane_index, d, k, vcfg, l_valid))(plane_dev, keys)
+        cols = column_operand(plane_index, devs, icfg, l_valid=l_valid,
+                              ct=ct, kt=kt)
+    return _programmed_sums(litw, plane_index, cols, icfg, cfg, bt=bt,
+                            ct=ct, kt=kt, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("bt", "ct", "kt", "interpret"))

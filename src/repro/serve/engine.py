@@ -94,6 +94,7 @@ from repro.core import tm
 from repro.core.imbue import IMBUEConfig
 from repro.core.tm import TMConfig
 from repro.core.variations import VariationConfig
+from repro.kernels import ops
 from repro.serve.batching import (QOS_BULK, Batch, BatcherConfig,
                                   DynamicBatcher, QueueFull,
                                   pack_request_np, validate_qos)
@@ -129,7 +130,8 @@ DEFAULT_COALESCED_PLANES_BACKEND = "coalesced-pallas-packed2"
 DEFAULT_COALESCED_SHARDED_BACKEND = "coalesced"
 
 
-def _resident_model_nbytes(state, backend: "api.Backend") -> int:
+def _resident_model_nbytes(state, backend: "api.Backend",
+                           columns: Optional[str] = None) -> int:
     """Programmed-model operand bytes the forward streams from HBM for
     ONE dispatch of ``state`` under ``backend``.
 
@@ -137,10 +139,16 @@ def _resident_model_nbytes(state, backend: "api.Backend") -> int:
     programmed cell; coalesced paths stream the include plane (uint32
     bitplane when packed); plane-packed states stream the uint32 index
     bitplane plus the optional f32 deviation plane — ISSUE 9's resident
-    reduction, surfaced as ``resident_bytes_per_dispatch``."""
+    reduction, surfaced as ``resident_bytes_per_dispatch``.  A read
+    whose column operand is programmed (``columns``, from the backend's
+    ``column_operand``) streams that f32 operand alone instead; one that
+    builds it in its own graph reads the planes."""
     caps = backend.capabilities
     if api.CAP_PACKED_PLANES in caps and getattr(state, "plane_packed",
                                                  False):
+        if columns == ops.COLUMNS_PROGRAMMED:
+            return 4 * (int(state.col_diff_t.size)
+                        + int(state.col_leak_t.size))
         n = int(state.plane_index.size) * 4
         dev = getattr(state, "plane_dev", None)
         if dev is not None:
@@ -156,6 +164,30 @@ def _resident_model_nbytes(state, backend: "api.Backend") -> int:
     if r is None:                        # DigitalState: the include plane
         return int(state.include.size) * 4
     return 2 * int(r.size) * 4
+
+
+class _ReplicaViews:
+    """Single-replica views ``[1, C, L]`` of a stack state for routed
+    dispatch, each sliced on its first use and kept: an ensemble pool
+    dispatches the whole stack and reads a view only for a health probe
+    or a canary, so it holds no second copy of its planes until then."""
+
+    def __init__(self, state, n: int):
+        self._state = state
+        self._views: List = [None] * n
+
+    def __getitem__(self, i: int):
+        if self._views[i] is None:
+            self._views[i] = self._state.replica_slice(i)
+        return self._views[i]
+
+
+def _replica_views(state, n: int):
+    """Routed dispatch's per-replica states: sliced views of a stack,
+    or ``state`` itself for every route of a single shared chip."""
+    if hasattr(state, "replica_slice"):
+        return _ReplicaViews(state, n)
+    return [state] * n
 
 
 @dataclasses.dataclass(frozen=True)
@@ -394,16 +426,16 @@ class ServeEngine:
             bcfg = bcfg.with_tuned_buckets(self.tuning["bucket_sizes"],
                                            self.backend.name)
         self.batcher = DynamicBatcher(bcfg, packed=self.packed_io)
+        tiles = (self.tuning or {}).get("tiles") or {}
+        self._tiles: Dict[str, int] = {
+            name: int(tiles[name]) for name in ("ct", "kt")
+            if name in tiles}
         # Pre-sliced single-replica states for routed dispatch (all share
         # one [1, C, L] shape -> one compiled kernel for every chip) and
         # ONE fused jit'd forward covering backend + argmax/vote.  A
         # coalesced pool has exactly one shared chip: every route lands
         # on the full state.
-        if hasattr(self.state, "replica_slice"):
-            self._slices = [self.state.replica_slice(i)
-                            for i in range(pool.n_replicas)]
-        else:
-            self._slices = [self.state] * pool.n_replicas
+        self._slices = _replica_views(self.state, pool.n_replicas)
         self._refresh_resident_nbytes()
         self._fwd = self._build_forward()
         self._next_rid = 0
@@ -445,11 +477,7 @@ class ServeEngine:
         fused = CAP_FUSED_KERNEL in backend.capabilities
         kernel_opts: Dict[str, object] = {}
         if fused:
-            kernel_opts["interpret"] = self.ecfg.interpret
-            tiles = (self.tuning or {}).get("tiles") or {}
-            for name in ("ct", "kt"):
-                if name in tiles:
-                    kernel_opts[name] = int(tiles[name])
+            kernel_opts = dict(self._tiles, interpret=self.ecfg.interpret)
         routing = self.ecfg.routing
         mode = self.ecfg.ensemble_mode
 
@@ -738,6 +766,11 @@ class ServeEngine:
                 self.metrics.note_forward_fallback(
                     self.selection.fallback_reason)
             canary = self._take_canary_turn()
+            ensemble = self.ecfg.routing == "ensemble"
+            self.metrics.note_column_operand(
+                self._column_source(canary.state) if canary is not None
+                else self._columns_full if ensemble
+                else self._columns_slice)
             if canary is not None:
                 # Canary dispatch: the candidate chip SERVES this batch, and
                 # the stable pool shadow-evaluates the same rows with the
@@ -746,7 +779,7 @@ class ServeEngine:
                 # real read, so its router load counter still advances.
                 sums, preds = self._fwd(canary.state, lits, key,
                                         self._mask_one, bt=batch.bucket)
-                if self.ecfg.routing == "ensemble":
+                if ensemble:
                     _, shadow = self._fwd(self.state, lits, key,
                                           self._healthy_mask, bt=batch.bucket)
                     for i in self.router.healthy_replicas():
@@ -756,8 +789,7 @@ class ServeEngine:
                     _, shadow = self._fwd(self._slices[stable], lits, key,
                                           self._mask_one, bt=batch.bucket)
                     self.router.note_dispatch(stable, batch.bucket)
-                shadow_nbytes = (self._resident_full
-                                 if self.ecfg.routing == "ensemble"
+                shadow_nbytes = (self._resident_full if ensemble
                                  else self._resident_slice)
                 return InFlight(batch=batch, seq=seq, sums=sums, preds=preds,
                                 replica=CANARY, t_dispatch=t_dispatch,
@@ -765,9 +797,10 @@ class ServeEngine:
                                 blocked_snapshot=self._blocked_s,
                                 version=canary.version, shadow_preds=shadow,
                                 resident_nbytes=_resident_model_nbytes(
-                                    canary.state, self.backend)
+                                    canary.state, self.backend,
+                                    self._column_source(canary.state))
                                 + shadow_nbytes)
-            if self.ecfg.routing == "ensemble":
+            if ensemble:
                 sums, preds = self._fwd(self.state, lits, key,
                                         self._healthy_mask, bt=batch.bucket)
                 replica = ENSEMBLE
@@ -954,23 +987,30 @@ class ServeEngine:
                 state = state.pack_planes()
         self.pool = pool
         self.state = state
-        if hasattr(state, "replica_slice"):
-            self._slices = [state.replica_slice(i)
-                            for i in range(pool.n_replicas)]
-        else:
-            self._slices = [state] * pool.n_replicas
+        self._slices = _replica_views(state, pool.n_replicas)
         self._refresh_resident_nbytes()
 
+    def _column_source(self, state) -> Optional[str]:
+        """Where a dispatch of ``state`` takes its column operand from
+        (``"programmed"``, ``"built"``), None where it takes none."""
+        source = self.backend.column_operand
+        return None if source is None else source(
+            state, not self._noise_free, **self._tiles)
+
     def _refresh_resident_nbytes(self) -> None:
-        """Per-dispatch resident operand bytes for the full state
-        (ensemble dispatch) and one replica slice (routed dispatch) —
-        recomputed whenever the pool changes, since fault injection can
-        grow a nominal plane-packed pool a deviation plane (which also
-        changes the kernel's dot mode)."""
-        self._resident_full = _resident_model_nbytes(self.state,
-                                                     self.backend)
-        self._resident_slice = _resident_model_nbytes(self._slices[0],
-                                                      self.backend)
+        """Per-dispatch resident operand bytes and column-operand source
+        for the full state (ensemble dispatch) and one replica slice
+        (routed dispatch) — recomputed whenever the pool changes, since
+        fault injection can grow a nominal plane-packed pool a deviation
+        plane (which also changes the kernel's dot mode)."""
+        one = (jax.eval_shape(lambda s: s.replica_slice(0), self.state)
+               if hasattr(self.state, "replica_slice") else self.state)
+        self._columns_full = self._column_source(self.state)
+        self._columns_slice = self._column_source(one)
+        self._resident_full = _resident_model_nbytes(
+            self.state, self.backend, self._columns_full)
+        self._resident_slice = _resident_model_nbytes(
+            one, self.backend, self._columns_slice)
         dots = self.backend.dot_mode
         self.metrics.crossbar_dots = (
             None if dots is None else dots(self.state, not self._noise_free))

@@ -114,6 +114,10 @@ class ServeMetrics:
         # How the forward's crossbar column dots run (set by the engine
         # with its state and backend; None off the analog paths).
         self.crossbar_dots: Optional[str] = None
+        # Dispatches whose crossbar column operand was the programmed
+        # one the state carries, and those that built it in-graph.
+        self.column_operand: Dict[str, int] = {"programmed": 0,
+                                               "built": 0}
         self.t_first: Optional[float] = None
         self.t_last: Optional[float] = None
         # Capability-selection fallbacks (distinct reasons + count of
@@ -323,6 +327,12 @@ class ServeMetrics:
         busy = self.overlapped_s + self.device_wait_s
         return self.overlapped_s / busy if busy > 0 else 0.0
 
+    def note_column_operand(self, source: Optional[str]) -> None:
+        """Count one dispatch by where its column operand came from
+        (``"programmed"`` or ``"built"``); None takes none."""
+        if source is not None:
+            self.column_operand[source] += 1
+
     def record_batch(self, records: List[RequestRecord], bucket: int,
                      nbytes: int = 0, resident_nbytes: int = 0) -> None:
         """Account one dispatched batch; ``nbytes`` is the size of the
@@ -429,6 +439,7 @@ class ServeMetrics:
                    self.resident_bytes / self.batches
                    if self.batches else 0.0),
                "crossbar_dots": self.crossbar_dots,
+               "column_operand": dict(self.column_operand),
                "forward_fallbacks": list(self.forward_fallbacks),
                "fallback_dispatches": self.fallback_dispatches,
                "host_pack_s": self.host_pack_s,

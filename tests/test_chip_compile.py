@@ -69,7 +69,11 @@ def _operands(model: str, sharding):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
+    l_pad = -(-l // ops.KT_ANALOG) * ops.KT_ANALOG
+    c_pad = -(-c // ops.CT) * ops.CT
     return cfg, dict(
+        col_diff_t=s((N_REPLICAS, l_pad, c_pad), jnp.float32),
+        col_leak_t=s((N_REPLICAS, l_pad // 32, c_pad), jnp.float32),
         lits=s((BUCKET, l), jnp.uint8),
         litw=s((BUCKET, lw), jnp.uint32),
         include=s((c, l), jnp.bool_),
@@ -111,13 +115,19 @@ def _lower(kernel: str, cfg, o):
         "imbue_infer_planes_dev":
             lambda: ops.imbue_class_sums_stack_planes.lower(
                 o["litw"], o["incw"], o["dev"], icfg, cfg, **planes),
+        "imbue_infer_planes_programmed":
+            lambda: ops.imbue_class_sums_stack_planes.lower(
+                o["litw"], o["incw"], o["dev"], icfg, cfg,
+                col_diff_t=o["col_diff_t"], col_leak_t=o["col_leak_t"],
+                **planes),
     }[kernel]()
 
 
 KERNELS = ("clause_eval", "clause_eval_packed", "tm_infer", "tm_infer_packed",
            "coalesced", "coalesced_packed", "coalesced_planes",
            "imbue_infer_stack", "imbue_infer_packed_stack",
-           "imbue_infer_planes_nominal", "imbue_infer_planes_dev")
+           "imbue_infer_planes_nominal", "imbue_infer_planes_dev",
+           "imbue_infer_planes_programmed")
 
 
 @pytest.mark.parametrize("model", MODELS)
@@ -134,10 +144,11 @@ def test_kernel_compiles_for_v5e(kernel, model, one_chip, no_compile_cache):
 TRACED_ENGINES = {
     "kws6-nominal-r1": dict(shape=(6, 300, 377), density=0.006,
                             replicas=1, d2d=False, routing="round_robin",
-                            bucket=32, dots="default"),
+                            bucket=32, dots="default", columns=None),
     "fmnist-d2d-r4-ensemble": dict(shape=(10, 500, 784), density=0.0033,
                                    replicas=4, d2d=True, routing="ensemble",
-                                   bucket=128, dots="bf16x3"),
+                                   bucket=128, dots="bf16x3",
+                                   columns="programmed"),
 }
 
 
@@ -177,6 +188,7 @@ def test_forward_and_kernel_keep_the_names_the_trace_reads(
     assert engine.backend.name == "analog-pallas-packed2"
     assert not engine.selection.fell_back
     assert engine.summary()["crossbar_dots"] == e["dots"]
+    assert engine._columns_full == e["columns"]
     ensemble = e["routing"] == "ensemble"
     state = engine.state if ensemble else engine._slices[0]
     mask = engine._healthy_mask if ensemble else engine._mask_one
